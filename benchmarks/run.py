@@ -22,6 +22,7 @@ import traceback
 from pathlib import Path
 
 from benchmarks import common
+from repro.launch.compile_cache import use_compile_cache
 
 SECTIONS = [
     "storage",          # Tables 3/4/5/6
@@ -45,6 +46,7 @@ SECTIONS = [
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="comma-separated section list")
     ap.add_argument("--quick", action="store_true",

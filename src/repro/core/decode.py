@@ -59,6 +59,7 @@ class DecodeStats:
     fallback_streams: int = counter()  # streams decoded per-stream on host
     demoted_streams: int = counter()   # kernel-eligible streams demoted at run time
     kernel_launches: int = counter()   # fused launches + per-feature host calls
+    fused_launches: int = counter()    # batched kernel launches alone
     fused_s: float = counter(0.0)      # extract_s attribution: batched path
     fallback_s: float = counter(0.0)   # extract_s attribution: per-stream path
     # Table-9-style stage split (§6.3): the four sum to ~total decode time
@@ -234,6 +235,7 @@ class PallasDecodeEngine(DecodeEngine):
         words = buf.view("<i4").reshape(-1, 128)
         out = kops.xor_decrypt(jnp.asarray(words), use_pallas=self.use_pallas)
         self.stats.kernel_launches += 1
+        self.stats.fused_launches += 1
         return np.asarray(out).reshape(-1).view(np.uint8)[:n]
 
     def _dense_launch(
@@ -255,6 +257,7 @@ class PallasDecodeEngine(DecodeEngine):
             use_pallas=self.use_pallas,
         )
         self.stats.kernel_launches += 1
+        self.stats.fused_launches += 1
         res = np.asarray(out)
         return [res[j, :rows].view(np.float32) for j in range(len(vals_list))]
 
@@ -308,6 +311,7 @@ class PallasDecodeEngine(DecodeEngine):
             use_pallas=self.use_pallas,
         )
         self.stats.kernel_launches += 1
+        self.stats.fused_launches += 1
         flat = np.ascontiguousarray(np.asarray(out).reshape(-1))
         return [
             np.frombuffer(flat, dt, nb // dt.itemsize, int(out_off[r]) * 4)

@@ -18,6 +18,7 @@ that makes read over-scoping measurable.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import queue
 import threading
@@ -59,12 +60,16 @@ class WorkerMetrics:
     fused_features: int = counter()            # ops served by fused kernels
     fallback_features: int = counter()         # ops served per-feature
     kernel_launches: int = counter()           # fused + per-feature calls
+    fused_launches: int = counter()            # fused wave launches alone
+    demoted_features: int = counter()          # fused ops demoted to numpy
     transform_fused_s: float = counter(0.0)    # transform_s: fused path
     transform_fallback_s: float = counter(0.0) # transform_s: numpy path
     # per-engine extract accounting (mirrored from DecodeStats):
     extract_fused_s: float = counter(0.0)      # decode: batched-kernel path
     extract_fallback_s: float = counter(0.0)   # decode: per-stream path
     decode_launches: int = counter()           # decode kernel launches
+    decode_fused_launches: int = counter()     # batched decode launches alone
+    demoted_streams: int = counter()           # streams demoted to per-stream
     # per-extent I/O sizes of this worker's stripe fetches (Table 6)
     io_sizes: List[int] = counter(factory=list)
 
@@ -205,7 +210,8 @@ class DPPWorker:
                 time.sleep(0.01)
                 continue
             try:
-                batches = self.process_split(reader, split)
+                with self._lease_kept():
+                    batches = self.process_split(reader, split)
             except Exception:
                 # Extract/transform raised on this split's bytes.  The
                 # worker is fine — only the data is suspect — so report a
@@ -241,6 +247,28 @@ class DPPWorker:
             # else: no ok report — the lease lapses and the split is
             # re-dispatched rather than marked done with dropped batches
         self.alive = False
+
+    @contextlib.contextmanager
+    def _lease_kept(self):
+        """Heartbeat from a side thread while this worker runs a split.
+        One stripe can outlast the lease (its kernels compile on first
+        use), and a lease that lapses under a live worker re-dispatches
+        the split, whose batches are then delivered twice."""
+        master, wid = self.master, self.worker_id
+        period = master.lease_s / 4
+        done = threading.Event()
+
+        def beat() -> None:
+            while not done.wait(period):
+                master.heartbeat(wid)
+
+        keeper = threading.Thread(target=beat, daemon=True)
+        keeper.start()
+        try:
+            yield
+        finally:
+            done.set()
+            keeper.join()
 
     # -- ETL -------------------------------------------------------------------
 
@@ -376,6 +404,8 @@ class DPPWorker:
                 m.fused_features = es.fused_features
                 m.fallback_features = es.fallback_features
                 m.kernel_launches = es.kernel_launches
+                m.fused_launches = es.fused_launches
+                m.demoted_features = es.demoted_features
                 m.transform_fused_s = es.fused_s
                 m.transform_fallback_s = es.fallback_s
 
@@ -417,6 +447,8 @@ class DPPWorker:
         m.extract_fused_s = ds.fused_s
         m.extract_fallback_s = ds.fallback_s
         m.decode_launches = ds.kernel_launches
+        m.decode_fused_launches = ds.fused_launches
+        m.demoted_streams = ds.demoted_streams
         t4 = time.perf_counter()
         _drain(final=True)
         t_load = time.perf_counter()

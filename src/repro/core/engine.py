@@ -83,6 +83,7 @@ class EngineStats:
     fallback_features: int = counter()   # op executions served by per-feature numpy
     demoted_features: int = counter()    # fused-eligible ops demoted at run time
     kernel_launches: int = counter()     # fused pallas_calls + per-feature op calls
+    fused_launches: int = counter()      # fused wave launches alone
     fused_s: float = counter(0.0)        # transform_s attribution: fused path
     fallback_s: float = counter(0.0)     # transform_s attribution: numpy path
 
@@ -446,6 +447,7 @@ class PallasEngine(TransformEngine):
                     if fop.borders is not None:
                         borders[j, : fop.borders.size] = fop.borders
                 out32 = self._launch(mat, codes, p0, p1, borders)
+                self.stats.fused_launches += 1
             self.stats.kernel_launches += 1
             self.stats.fused_features += feats
             # vectorized unpack: at most one widening cast for the whole

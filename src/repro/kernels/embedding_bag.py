@@ -1,10 +1,11 @@
 """Pallas TPU kernel: pooled embedding-bag (DLRM's hot sparse op).
 
-TPU adaptation of the GPU gather: the grid walks (batch_tile, bag_slot);
-for each slot the scalar-prefetched ids pick the embedding-table block to
-stream into VMEM (BlockSpec index_map reads the prefetch ref), and the
-output tile accumulates mask-weighted rows across the L bag slots — a
-gather expressed as data-dependent block scheduling instead of
+TPU adaptation of the GPU gather: the table stays in HBM and the grid
+walks tiles of ``block_bags`` bags.  For each tile the scalar-prefetched
+ids drive one row DMA per (bag, slot) into a VMEM scratch laid out
+slot-major, (L, bags, E), so every slot's rows form one aligned tile;
+the output tile then accumulates the mask-weighted rows across the L
+slots — a gather expressed as data-dependent DMAs instead of
 random-access loads.
 """
 from __future__ import annotations
@@ -17,60 +18,83 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(ids_ref, mask_ref, table_row_ref, out_ref, denom_ref, *, mean: bool):
-    l = pl.program_id(1)
-    n_l = pl.num_programs(1)
+def _kernel(ids_ref, mask_ref, table_hbm, out_ref, rows_ref, sem, *,
+            mean: bool):
+    slots, bags, _ = rows_ref.shape
+    base = pl.program_id(0) * bags * slots
 
-    @pl.when(l == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-        denom_ref[...] = jnp.zeros_like(denom_ref)
+    def copy(j):
+        b, l = j // slots, j % slots
+        return pltpu.make_async_copy(
+            table_hbm.at[pl.ds(ids_ref[base + j], 1)],
+            rows_ref.at[l, pl.ds(b, 1)],
+            sem,
+        )
 
-    m = mask_ref[0, l]                                 # scalar f32
-    out_ref[...] += table_row_ref[...] * m
-    denom_ref[...] += m
+    def start(j, carry):
+        copy(j).start()
+        return carry
 
+    def wait(j, carry):
+        copy(j).wait()
+        return carry
+
+    jax.lax.fori_loop(0, bags * slots, start, 0)
+    jax.lax.fori_loop(0, bags * slots, wait, 0)
+
+    mask = mask_ref[...]                               # (bags, L) f32
+    lane = jax.lax.broadcasted_iota(jnp.int32, mask.shape, 1)
+    acc = jnp.zeros(out_ref.shape, jnp.float32)
+    for l in range(slots):
+        m = jnp.sum(jnp.where(lane == l, mask, 0.0), axis=1, keepdims=True)
+        acc = acc + rows_ref[l].astype(jnp.float32) * m
     if mean:
-        @pl.when(l == n_l - 1)
-        def _finish():
-            out_ref[...] = out_ref[...] / jnp.maximum(denom_ref[...], 1.0)
+        acc = acc / jnp.maximum(jnp.sum(mask, axis=1, keepdims=True), 1.0)
+    out_ref[...] = acc.astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("mode", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("mode", "block_bags", "interpret")
+)
 def embedding_bag(
     table: jax.Array,     # (V, E) f32
     ids: jax.Array,       # (B, L) int32
     mask: jax.Array,      # (B, L) f32
     *,
     mode: str = "mean",   # "mean" | "sum" (static: picks the finish pass)
+    block_bags: int = 8,
     interpret: bool = False,
 ) -> jax.Array:
     """Pooled bag: out[b] = sum_l mask[b,l] * table[ids[b,l]], divided by
-    max(sum(mask), 1) when ``mode="mean"`` (the DLRM pooling denominator)."""
+    max(sum(mask), 1) when ``mode="mean"`` (the DLRM pooling denominator).
+    Ids are clipped into the table, like ``DLRM.pooled_embeddings``."""
     if mode not in ("mean", "sum"):
         raise ValueError(f"mode must be 'mean' or 'sum', got {mode!r}")
     v, e = table.shape
     b, l = ids.shape
+    bt = min(block_bags, b)
+    nblk = pl.cdiv(b, bt)
+    pad = nblk * bt - b
+    # padded bags read row 0 under a zero mask; ids ride flat in SMEM
+    flat_ids = jnp.pad(jnp.clip(ids, 0, v - 1), ((0, pad), (0, 0))).reshape(-1)
+    mask = jnp.pad(mask.astype(jnp.float32), ((0, pad), (0, 0)))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, l),
-        in_specs=[
-            pl.BlockSpec((1, l), lambda i, j, ids_p: (i, 0)),        # mask row
-            pl.BlockSpec((1, e), lambda i, j, ids_p: (ids_p[i, j], 0)),  # table row
-        ],
-        out_specs=[
-            pl.BlockSpec((1, e), lambda i, j, ids_p: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, ids_p: (i, 0)),
-        ],
-    )
-    out, _ = pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, mean=(mode == "mean")),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, e), table.dtype),
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nblk,),
+            in_specs=[
+                pl.BlockSpec((bt, l), lambda i, ids_p: (i, 0)),   # mask tile
+                pl.BlockSpec(memory_space=pl.ANY),                # table (HBM)
+            ],
+            out_specs=pl.BlockSpec((bt, e), lambda i, ids_p: (i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((l, bt, e), table.dtype),
+                pltpu.SemaphoreType.DMA,
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((nblk * bt, e), table.dtype),
         interpret=interpret,
-    )(ids, mask.astype(jnp.float32), table)
-    return out
+    )(flat_ids, mask, table)
+    return out[:b]

@@ -39,13 +39,15 @@ OP_BUCKETIZE_F = 6    # float32 lanes: searchsorted-left over borders[f]
 
 def _kernel(ids_ref, code_ref, p0_ref, p1_ref, borders_ref, out_ref):
     ids = ids_ref[...]                             # (br, bc) i32
-    code = code_ref[...][0][None, :]               # (1, bc) -> broadcast
-    p0 = p0_ref[...][0][None, :]
-    p1 = p1_ref[...][0][None, :]
-    borders = borders_ref[...]                     # (bc, nb) f32
+    code = code_ref[...]                           # (1, bc) -> broadcast
+    p0 = p0_ref[...]
+    p1 = p1_ref[...]
 
     h = _hash_u32(ids.astype(jnp.uint32) ^ p0.astype(jnp.uint32))
-    out_hash = (h % jnp.maximum(p1.astype(jnp.uint32), 1)).astype(jnp.int32)
+    # unsigned max(p1, 1) without an unsigned max (Mosaic has none)
+    out_hash = (h % jnp.where(p1 == 0, 1, p1).astype(jnp.uint32)).astype(
+        jnp.int32
+    )
     m = jnp.maximum(p1, 1)
     # jnp.mod floors to the divisor's sign, so one mod lands in [0, m);
     # adding m before a second mod would overflow int32 for m near 2^31
@@ -62,8 +64,11 @@ def _kernel(ids_ref, code_ref, p0_ref, p1_ref, borders_ref, out_ref):
     out_clamp_f = jax.lax.bitcast_convert_type(
         jnp.clip(f, lo, hi), jnp.int32
     )
-    out_bucket_f = jnp.sum(
-        f[:, :, None] > borders[None, :, :], axis=-1, dtype=jnp.int32
+    # one border row per step: a (br, bc, nb) compare would not fit VMEM
+    out_bucket_f = jax.lax.fori_loop(
+        0, borders_ref.shape[0],
+        lambda k, acc: acc + (f > borders_ref[pl.ds(k, 1), :]).astype(jnp.int32),
+        jnp.zeros(ids.shape, jnp.int32),
     )
 
     out = jnp.where(code == OP_SIGRID_HASH, out_hash, ids)
@@ -106,11 +111,11 @@ def fused_transform(
                 pl.BlockSpec((1, bc), lambda i, j: (0, j)),
                 pl.BlockSpec((1, bc), lambda i, j: (0, j)),
                 pl.BlockSpec((1, bc), lambda i, j: (0, j)),
-                pl.BlockSpec((bc, nb), lambda i, j: (j, 0)),
+                pl.BlockSpec((nb, bc), lambda i, j: (0, j)),
             ],
             out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((rows, feats), jnp.int32),
         interpret=interpret,
     )(ids, row(op_codes), row(param0), row(param1),
-      borders.astype(jnp.float32))
+      borders.astype(jnp.float32).T)           # (nb, features): rows per border

@@ -20,14 +20,21 @@ import numpy as np
 import jax
 
 from repro import configs as cfglib
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.dlrm import DLRMConfig
 from repro.optim import OptimizerConfig
 from repro.train import Trainer, TrainerConfig
 
+STRIPE_ROWS = 512       # rows per DWRF stripe of the synthetic warehouse
+
 
 def dlrm_dpp_batches(cfg: DLRMConfig, batch_size: int, n_partitions: int = 2,
-                     rows_per_partition: int = 2048, n_workers: int = 2):
-    """Build a synthetic warehouse + DPP session; yield tensor batches."""
+                     rows_per_partition: int = 2048, n_workers: int = 2,
+                     engine: str = "numpy", decode_engine: str = "numpy"):
+    """Build a synthetic warehouse + DPP session; yield tensor batches.
+
+    ``engine``/``decode_engine`` pick the transform and extract engines of
+    every DPP worker ("pallas" launches the fused kernels)."""
     from repro.core import dwrf
     from repro.core.datagen import DataGenConfig
     from repro.core.dpp import DPPSession, SessionSpec
@@ -42,7 +49,7 @@ def dlrm_dpp_batches(cfg: DLRMConfig, batch_size: int, n_partitions: int = 2,
     table.generate(
         n_partitions,
         DataGenConfig(rows_per_partition=rows_per_partition, seed=1),
-        dwrf.DwrfWriterOptions(flattened=True, stripe_rows=512),
+        dwrf.DwrfWriterOptions(flattened=True, stripe_rows=STRIPE_ROWS),
     )
     dense = schema.dense_ids[: cfg.num_dense]
     n_gen = max(cfg.num_tables // 4, 0)
@@ -57,12 +64,15 @@ def dlrm_dpp_batches(cfg: DLRMConfig, batch_size: int, n_partitions: int = 2,
         feature_ids=tuple(pipe.required_features()),
         transform_specs=tuple(pipe.specs),
         batch_size=batch_size,
-        rows_per_split=512,
+        # whole stripes per split, and at least one batch per split: a
+        # split shorter than the batch would cut every batch to its size
+        rows_per_split=-(-batch_size // STRIPE_ROWS) * STRIPE_ROWS,
         dense_keys=tuple(f"d{f}" for f in dense),
         sparse_keys=tuple(f"s{f}" for f in sparse) + tuple(f"g{j}" for j in range(n_gen)),
         max_ids_per_feature=cfg.max_ids_per_feature,
     )
-    session = DPPSession(spec, table, n_workers=n_workers, auto_scale=True)
+    session = DPPSession(spec, table, n_workers=n_workers, auto_scale=True,
+                         engine=engine, decode_engine=decode_engine)
     session.start()
 
     def gen():
@@ -98,7 +108,26 @@ def lm_synthetic_batches(cfg, batch_size: int, seq: int, seed: int = 0):
         yield batch
 
 
+def session_ok(session) -> bool:
+    """A session that ended must have COMPLETED, and no split may sit in
+    quarantine; one still RUNNING (the trainer stopped at ``--steps``
+    first) is fine.  Prints the failure report when it is not."""
+    from repro.core.dpp.master import SessionState
+
+    ended = session.master.finished
+    bad = session.master.quarantined or (
+        ended and session.state != SessionState.COMPLETED
+    )
+    if bad:
+        print(f"dpp session {session.state}: "
+              f"{len(session.master.quarantined)} split(s) quarantined")
+        for f in session.failure_report():
+            print(f"split {f.split_id}: {f.last_error}")
+    return not bad
+
+
 def main() -> int:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dlrm-paper")
     ap.add_argument("--steps", type=int, default=50)
@@ -127,17 +156,26 @@ def main() -> int:
         batches = lm_synthetic_batches(cfg, args.batch_size, args.seq)
 
     t0 = time.time()
-    state = trainer.fit(batches)
+    try:
+        state = trainer.fit(batches)
+    finally:
+        if session is not None:
+            session.stop()
     wall = time.time() - t0
     losses = [m.loss for m in trainer.history]
     print(f"arch={cfg.name} steps={state['step']} wall_s={wall:.1f}")
-    print(f"loss: first={losses[0]:.4f} last={losses[-1]:.4f}")
+    ok = bool(losses)
+    if losses:
+        print(f"loss: first={losses[0]:.4f} last={losses[-1]:.4f}")
+        ok = losses[-1] < losses[0]
     print(f"data_stall_fraction={trainer.stall_fraction():.3f}")
     if session is not None:
         m = session.worker_metrics()
-        print(f"dpp: storage_rx={m.storage_rx_bytes} tx={m.tx_bytes} "
+        print(f"dpp: state={session.state} data_errors={m.data_errors} "
+              f"storage_rx={m.storage_rx_bytes} tx={m.tx_bytes} "
               f"breakdown={ {k: round(v, 3) for k, v in m.cycle_breakdown().items()} }")
-    return 0 if losses[-1] < losses[0] else 1
+        ok = session_ok(session) and ok
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":

@@ -218,7 +218,6 @@ class DLRM:
         return n if (self.cfg.vocab_per_table % n == 0) else 1
 
     def pooled_embeddings_sharded(self, tables, batch, mesh):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         c = self.cfg
@@ -241,15 +240,14 @@ class DLRM:
             denom = jnp.maximum(jnp.sum(mask, axis=2), 1.0)
             return part / denom[..., None].astype(part.dtype)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, "model", None), P(None, None, None), P(None, None, None)),
             out_specs=P(None, None, None),
-            check_rep=False,
+            check_vma=False,
         )(tables, batch["sparse_ids"], batch["sparse_mask"])
 
     def sparse_table_update_sharded(self, tables, acc, dpooled, batch, lr, mesh, eps=1e-8):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         c = self.cfg
@@ -286,12 +284,12 @@ class DLRM:
             ac_new = ac_pad.reshape(t, v_loc + 1)[:, :v_loc]
             return tb_new, ac_new
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, "model", None), P(None, "model"),
                       P(None, None, None), P(None, None, None), P(None, None, None)),
             out_specs=(P(None, "model", None), P(None, "model")),
-            check_rep=False,
+            check_vma=False,
         )(tables, acc, dpooled, batch["sparse_ids"], batch["sparse_mask"])
 
     def normalized_entropy(self, params, batch) -> jax.Array:

@@ -195,7 +195,6 @@ def moe_forward_ep(
     m: MoEConfig,
 ) -> Tuple[jax.Array, jax.Array]:
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     b, s, d = x.shape
     n_model = mesh.shape["model"]
@@ -219,7 +218,7 @@ def moe_forward_ep(
         aux = jax.lax.pmean(aux, "model")
         return y.reshape(xb.shape), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -230,7 +229,7 @@ def moe_forward_ep(
             P("model", None, None),
         ),
         out_specs=(P(batch_axes, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["wi_gate"], params["wi_up"], params["wo"])
 
     if m.num_shared_experts:

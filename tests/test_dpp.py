@@ -63,6 +63,27 @@ def test_worker_failure_restart_completes_epoch():
     assert len(sess.restart_events) >= 1
 
 
+def test_stripe_slower_than_lease_is_delivered_once():
+    """A live worker whose stripe outlasts the lease (kernels compiling
+    on first use) keeps its lease: the split is neither re-dispatched nor
+    delivered twice."""
+    from repro.core.engine import NumpyEngine
+
+    class FirstStripeSlow(NumpyEngine):
+        def run(self, batch):
+            if self.stats.fallback_features == 0:
+                time.sleep(0.6)
+            return super().run(batch)
+
+    t = _table(n_partitions=1)
+    sess = DPPSession(_spec(t, rows_per_split=512), t, n_workers=2,
+                      lease_s=0.2, engine=FirstStripeSlow)
+    batches = sess.run_to_completion(timeout_s=60)
+    assert sum(b["label"].shape[0] for b in batches) == 1024
+    assert sess.worker_metrics().rows_done == 1024
+    assert set(sess.master.checkpoint()["dispatches"].values()) == {1}
+
+
 def test_master_checkpoint_restore_resumes():
     t = _table()
     spec = _spec(t)

@@ -12,10 +12,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 import dataclasses
+from jax.sharding import AxisType
 from repro.launch.steps import make_train_step, make_decode_step
 from repro import configs as cfglib
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 # 1) train step compiles + runs for a smoke MoE config on the mesh
 cfg = cfglib.get_smoke_config("deepseek-v2-236b")
@@ -64,7 +65,7 @@ print("decode_compile_ok")
 def test_steps_on_virtual_mesh():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"        # eight virtual CPU devices
     r = subprocess.run([sys.executable, "-c", SUB], capture_output=True,
                        text=True, env=env, timeout=900, cwd=os.path.dirname(os.path.dirname(__file__)))
     assert "moe_train_ok" in r.stdout, r.stdout + r.stderr

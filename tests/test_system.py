@@ -68,3 +68,38 @@ def test_one_epoch_semantics():
     sess = DPPSession(spec, t, n_workers=2)
     batches = sess.run_to_completion(timeout_s=60)
     assert sum(b["label"].shape[0] for b in batches) == 1024   # exactly one epoch
+
+
+def test_dpp_batches_follow_batch_size_above_one_stripe():
+    """A batch wider than a 512-row stripe gets splits of whole stripes
+    at least that wide, so no batch is cut to the split's size."""
+    from repro.core.dpp.master import SessionState
+    from repro.launch.train import session_ok
+
+    cfg = cfglib.get_smoke_config("dlrm-paper")
+    batches, session = dlrm_dpp_batches(cfg, batch_size=1024, n_partitions=1,
+                                        rows_per_partition=2048, n_workers=1)
+    rows = [len(b["label"]) for b in batches]
+    assert rows == [1024, 1024]
+    assert session.state == SessionState.COMPLETED
+    assert session_ok(session)
+
+
+def test_session_ok_rejects_an_ended_session_that_did_not_complete(capsys):
+    from types import SimpleNamespace
+
+    from repro.core.dpp.master import SessionState
+    from repro.launch.train import session_ok
+
+    failure = SimpleNamespace(split_id=3, last_error="boom")
+
+    def fake(state, finished, quarantined):
+        master = SimpleNamespace(finished=finished, quarantined=quarantined)
+        return SimpleNamespace(master=master, state=state,
+                               failure_report=lambda: list(quarantined.values()))
+
+    assert session_ok(fake(SessionState.COMPLETED, True, {}))
+    assert session_ok(fake(SessionState.RUNNING, False, {}))   # stopped at --steps
+    assert not session_ok(fake(SessionState.DEGRADED, True, {3: failure}))
+    assert "split 3: boom" in capsys.readouterr().out
+    assert not session_ok(fake(SessionState.RUNNING, False, {3: failure}))
