@@ -66,14 +66,21 @@ def log_kernel_shapes(shapes):
         setattr(ops, name, wrapped)
 
 
-def record_compiles(events):
+def record_compiles(events, cache):
+    """Backend compile durations (a persistent-cache hit logs one too: its
+    read), and the persistent cache's hits and misses."""
     import jax
 
-    def listen(name, secs, **kw):
+    def duration(name, secs, **kw):
         if name == "/jax/core/compile/backend_compile_duration":
             events.append(secs)
 
-    jax.monitoring.register_event_duration_secs_listener(listen)
+    def event(name, **kw):
+        if name.startswith("/jax/compilation_cache/cache_"):
+            cache[name.rsplit("_", 1)[1]] += 1      # "hits" | "misses"
+
+    jax.monitoring.register_event_duration_secs_listener(duration)
+    jax.monitoring.register_event_listener(event)
 
 
 def batch_digest(batch) -> str:
@@ -193,16 +200,16 @@ def train(cfg):
 
 
 def main() -> None:
-    use_compile_cache()
+    cache_dir = use_compile_cache()
     import jax
 
     devices = jax.devices()
     if devices[0].platform != "tpu":
         fail(f"no TPU: JAX found {devices[0].platform!r} devices")
-    smoke(devices)
+    smoke(devices, cache_dir)
 
 
-def smoke(devices) -> None:
+def smoke(devices, cache_dir: str) -> None:
     from repro import configs as cfglib
 
     dev = devices[0]
@@ -210,8 +217,9 @@ def smoke(devices) -> None:
 
     shapes = collections.defaultdict(set)
     compiles = []
+    cache = collections.Counter()
     log_kernel_shapes(shapes)
-    record_compiles(compiles)
+    record_compiles(compiles, cache)
 
     paper = cfglib.get_config("dlrm-paper")
     rows = FULL_ROWS // SHARDS
@@ -265,7 +273,8 @@ def smoke(devices) -> None:
     print(f"smoke figure, not a benchmark: {rate!r} steps/s over steps "
           f"2..{steps} at batch {BATCH} (wall {wall!r} s incl. compile)")
     print(f"compile: {len(compiles)} backend compiles, {sum(compiles)!r} s in "
-          f"all, largest {max(compiles, default=0.0)!r} s")
+          f"all, largest {max(compiles, default=0.0)!r} s; persistent cache "
+          f"{cache_dir}: {cache['hits']} hits, {cache['misses']} misses")
     peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
     print(f"memory: peak_bytes_in_use={peak}")
     print(f"loss: step 1 {losses[0]!r} vs DLRM.loss at highest precision "

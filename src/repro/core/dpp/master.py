@@ -4,7 +4,8 @@ Responsibilities (paper-faithful):
   * break the preprocessing workload into self-contained **splits**
     (successive row ranges of the dataset) and serve them to Workers,
   * track split progress; re-dispatch splits whose lease expired
-    (worker failure / straggler mitigation),
+    (worker failure / straggler mitigation), and let only the first
+    finished copy of a re-dispatched split be delivered,
   * periodic **checkpoints** of reader state for restore-on-failure,
   * worker health monitoring (heartbeats) with automatic restart hooks.
 
@@ -137,6 +138,7 @@ class DPPMaster:
         self._pending: List[int] = []
         self._leased: Dict[int, _Lease] = {}
         self._done: set = set()
+        self._delivering: Dict[int, str] = {}     # split -> worker delivering it
         self._dispatches: Dict[int, int] = {}     # split -> times leased
         self._failures: Dict[int, List[FailureReport]] = {}
         self._quarantined: Dict[int, SplitFailure] = {}
@@ -182,6 +184,40 @@ class DPPMaster:
         with self._lock:
             return [self._splits[sid] for sid in self._pending[:n]]
 
+    def claim_delivery(self, worker_id: str, split_id: int) -> bool:
+        """Exactly-once delivery.  A split re-dispatched after its lease
+        lapsed can be processed twice (its first holder was slow, not
+        lost), so the first worker to finish claims the right to deliver
+        its batches.  Every later copy is refused; the refused worker drops
+        its batches, and its lease, if it still holds one, is released
+        without a charge."""
+        with self._lock:
+            holder = self._delivering.get(split_id, worker_id)
+            if split_id in self._done or holder != worker_id:
+                lease = self._leased.get(split_id)
+                if lease is not None and lease.worker_id == worker_id:
+                    del self._leased[split_id]
+                return False
+            self._delivering[split_id] = worker_id
+            return True
+
+    def abandon_delivery(self, worker_id: str, split_id: int) -> None:
+        """The claimant was stopped before it placed all its batches:
+        release the claim and re-queue the split, unless a re-dispatch
+        still holds its lease.  Not charged: a stop is neither a lost
+        worker nor bad data."""
+        with self._lock:
+            if self._delivering.get(split_id) != worker_id:
+                return
+            del self._delivering[split_id]
+            lease = self._leased.get(split_id)
+            if lease is not None and lease.worker_id != worker_id:
+                return
+            self._leased.pop(split_id, None)
+            if (split_id not in self._done and split_id not in self._quarantined
+                    and split_id not in self._pending):
+                self._pending.insert(0, split_id)
+
     def complete_split(
         self,
         worker_id: str,
@@ -209,6 +245,7 @@ class DPPMaster:
             if status == REPORT_OK:
                 if owns:
                     del self._leased[split_id]
+                self._delivering.pop(split_id, None)
                 # a late ok un-quarantines: the split's batches WERE
                 # produced and delivered (e.g. a worker that out-slept its
                 # budget's worth of lease expiries but finished anyway), so

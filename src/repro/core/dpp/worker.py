@@ -18,7 +18,6 @@ that makes read over-scoping measurable.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import queue
 import threading
@@ -210,8 +209,7 @@ class DPPWorker:
                 time.sleep(0.01)
                 continue
             try:
-                with self._lease_kept():
-                    batches = self.process_split(reader, split)
+                batches, cached = self.process_split(reader, split)
             except Exception:
                 # Extract/transform raised on this split's bytes.  The
                 # worker is fine — only the data is suspect — so report a
@@ -224,6 +222,10 @@ class DPPWorker:
                     self.worker_id, split.split_id,
                     status=REPORT_DATA_ERROR, error=traceback.format_exc(),
                 )
+                continue
+            if not self.master.claim_delivery(self.worker_id, split.split_id):
+                # this copy outlived its lease and another dispatch of the
+                # split was delivered first: drop it, never deliver twice
                 continue
             delivered = True
             for batch in batches:
@@ -243,37 +245,23 @@ class DPPWorker:
                     delivered = False   # hard-stopped mid-delivery
                     break
             if delivered:
+                rows = split.row_end - split.row_start
+                self.metrics.splits_done += 1
+                self.metrics.rows_done += rows
+                if cached:
+                    self.metrics.rows_from_cache += rows
                 self.master.complete_split(self.worker_id, split.split_id)
-            # else: no ok report — the lease lapses and the split is
-            # re-dispatched rather than marked done with dropped batches
+            else:
+                # no ok report: the split is re-dispatched rather than
+                # marked done with dropped batches
+                self.master.abandon_delivery(self.worker_id, split.split_id)
         self.alive = False
-
-    @contextlib.contextmanager
-    def _lease_kept(self):
-        """Heartbeat from a side thread while this worker runs a split.
-        One stripe can outlast the lease (its kernels compile on first
-        use), and a lease that lapses under a live worker re-dispatches
-        the split, whose batches are then delivered twice."""
-        master, wid = self.master, self.worker_id
-        period = master.lease_s / 4
-        done = threading.Event()
-
-        def beat() -> None:
-            while not done.wait(period):
-                master.heartbeat(wid)
-
-        keeper = threading.Thread(target=beat, daemon=True)
-        keeper.start()
-        try:
-            yield
-        finally:
-            done.set()
-            keeper.join()
 
     # -- ETL -------------------------------------------------------------------
 
     def process_split(self, reader: TableReader, split: Split):
-        """Extract + transform + batch one split; returns tensor minibatches.
+        """Extract + transform + batch one split; returns its tensor
+        minibatches and whether the tensor cache served them.
 
         Two-stage pipeline: a producer thread streams the split's stripes
         from storage into a bounded prefetch queue; this (consumer) thread
@@ -292,10 +280,7 @@ class DPPWorker:
             key = TensorCache.key(self.spec, split, meta.generation)
             cached = self.tensor_cache.get(key)
             if cached is not None:
-                self.metrics.splits_done += 1
-                self.metrics.rows_done += split.row_end - split.row_start
-                self.metrics.rows_from_cache += split.row_end - split.row_start
-                return cached
+                return cached, True
 
         t_split0 = time.perf_counter()
         prefetch: "queue.Queue" = queue.Queue(self.prefetch_stripes)
@@ -334,7 +319,6 @@ class DPPWorker:
         # are not re-copied for every stripe that arrives.
         pending: List[Tuple[Dict[str, Any], Optional[np.ndarray], int]] = []
         pending_rows = 0
-        n_served = 0
 
         def _emit(env, labels, start, stop):
             sub_env = _slice_env(env, start, stop)
@@ -348,7 +332,7 @@ class DPPWorker:
             out.append(tensors)
 
         def _drain(final: bool) -> None:
-            nonlocal pending, pending_rows, n_served
+            nonlocal pending, pending_rows
             if pending_rows == 0 or (not final and pending_rows < bs):
                 return
             env = _concat_envs([p[0] for p in pending])
@@ -360,7 +344,6 @@ class DPPWorker:
             if final and start < pending_rows:
                 _emit(env, labels, start, pending_rows)
                 start = pending_rows
-            n_served += start
             if start < pending_rows:
                 pending = [(
                     _slice_env(env, start, pending_rows),
@@ -464,9 +447,7 @@ class DPPWorker:
             self.tensor_cache.put(key, out, cpu_s=time.perf_counter() - t_split0)
 
         m.tx_bytes += sum(sum(a.nbytes for a in b.values()) for b in out)
-        m.splits_done += 1
-        m.rows_done += n_served
-        return out
+        return out, False
 
     def _trace_transform(self, t0: float, t1: float, es, m: WorkerMetrics,
                          split_id: int) -> None:

@@ -1,7 +1,7 @@
 """Pallas TPU kernel: pooled embedding-bag (DLRM's hot sparse op).
 
 TPU adaptation of the GPU gather: the table stays in HBM and the grid
-walks tiles of ``block_bags`` bags.  For each tile the scalar-prefetched
+walks tiles of ``BLOCK_BAGS`` bags.  For each tile the scalar-prefetched
 ids drive one row DMA per (bag, slot) into a VMEM scratch laid out
 slot-major, (L, bags, E), so every slot's rows form one aligned tile;
 the output tile then accumulates the mask-weighted rows across the L
@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+BLOCK_BAGS = 8        # bags per grid step: one sublane tile of the output
 
 
 def _kernel(ids_ref, mask_ref, table_hbm, out_ref, rows_ref, sem, *,
@@ -53,16 +55,13 @@ def _kernel(ids_ref, mask_ref, table_hbm, out_ref, rows_ref, sem, *,
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("mode", "block_bags", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("mode", "interpret"))
 def embedding_bag(
     table: jax.Array,     # (V, E) f32
     ids: jax.Array,       # (B, L) int32
     mask: jax.Array,      # (B, L) f32
     *,
     mode: str = "mean",   # "mean" | "sum" (static: picks the finish pass)
-    block_bags: int = 8,
     interpret: bool = False,
 ) -> jax.Array:
     """Pooled bag: out[b] = sum_l mask[b,l] * table[ids[b,l]], divided by
@@ -72,7 +71,7 @@ def embedding_bag(
         raise ValueError(f"mode must be 'mean' or 'sum', got {mode!r}")
     v, e = table.shape
     b, l = ids.shape
-    bt = min(block_bags, b)
+    bt = min(BLOCK_BAGS, b)
     nblk = pl.cdiv(b, bt)
     pad = nblk * bt - b
     # padded bags read row 0 under a zero mask; ids ride flat in SMEM

@@ -16,8 +16,8 @@ def use_compile_cache() -> str:
     """Point JAX's persistent compilation cache at one fixed directory and
     return it.  ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it
     itself and nothing else is set.  Otherwise the cache lives in
-    ``.jax_cache/`` at the checkout root — a fixed path, because the path
-    is part of every cache key."""
+    ``.jax_cache/`` at the checkout root, so a later run from the same
+    checkout finds what an earlier one compiled."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -26,6 +26,10 @@ from repro.optim import OptimizerConfig
 from repro.train import Trainer, TrainerConfig
 
 STRIPE_ROWS = 512       # rows per DWRF stripe of the synthetic warehouse
+# DPP lease: workers heartbeat once per stripe, and on a TPU a stripe with
+# new shapes waits on Mosaic compiles of several seconds each.  A shorter
+# lease re-dispatches live splits (delivered once, but decoded twice).
+LEASE_S = 60.0
 
 
 def dlrm_dpp_batches(cfg: DLRMConfig, batch_size: int, n_partitions: int = 2,
@@ -72,7 +76,8 @@ def dlrm_dpp_batches(cfg: DLRMConfig, batch_size: int, n_partitions: int = 2,
         max_ids_per_feature=cfg.max_ids_per_feature,
     )
     session = DPPSession(spec, table, n_workers=n_workers, auto_scale=True,
-                         engine=engine, decode_engine=decode_engine)
+                         lease_s=LEASE_S, engine=engine,
+                         decode_engine=decode_engine)
     session.start()
 
     def gen():

@@ -1,3 +1,6 @@
+import collections
+import itertools
+import threading
 import time
 
 import numpy as np
@@ -63,25 +66,66 @@ def test_worker_failure_restart_completes_epoch():
     assert len(sess.restart_events) >= 1
 
 
-def test_stripe_slower_than_lease_is_delivered_once():
-    """A live worker whose stripe outlasts the lease (kernels compiling
-    on first use) keeps its lease: the split is neither re-dispatched nor
-    delivered twice."""
+def _row_counts(batches):
+    """Multiset of rows, each the bytes of its slice of every tensor."""
+    rows = collections.Counter()
+    for b in batches:
+        keys = sorted(b)
+        for i in range(len(b["label"])):
+            rows[b"".join(b[k][i].tobytes() for k in keys)] += 1
+    return rows
+
+
+@pytest.mark.parametrize("stall", ["slow", "wedged"])
+def test_stripe_slower_than_lease_is_delivered_once(stall):
+    """A worker stuck inside one stripe (slow: kernels compiling on first
+    use; or wedged outright) stops heartbeating, so its lease lapses and a
+    live worker re-runs the split.  The first finished copy is delivered
+    and any later one dropped: the epoch holds each row exactly once."""
     from repro.core.engine import NumpyEngine
 
-    class FirstStripeSlow(NumpyEngine):
+    t = _table(n_partitions=1)
+    spec = _spec(t, rows_per_split=512)
+    want = _row_counts(
+        DPPSession(spec, t, n_workers=1).run_to_completion(timeout_s=60)
+    )
+    calls = itertools.count()       # next() is atomic under the GIL
+    release = threading.Event()
+
+    class StallFirstStripe(NumpyEngine):
         def run(self, batch):
-            if self.stats.fallback_features == 0:
-                time.sleep(0.6)
+            if next(calls) == 0:    # the session's first stripe only
+                release.wait(0.6 if stall == "slow" else 60)
             return super().run(batch)
 
-    t = _table(n_partitions=1)
-    sess = DPPSession(_spec(t, rows_per_split=512), t, n_workers=2,
-                      lease_s=0.2, engine=FirstStripeSlow)
-    batches = sess.run_to_completion(timeout_s=60)
-    assert sum(b["label"].shape[0] for b in batches) == 1024
+    sess = DPPSession(spec, t, n_workers=2, lease_s=0.2,
+                      engine=StallFirstStripe)
+    client = sess.clients[0]
+    got = []
+    sess.start()
+    try:
+        deadline = time.time() + 60
+        while sum(want.values()) > sum(len(b["label"]) for b in got):
+            assert time.time() < deadline, "the epoch never completed"
+            b = client.get_batch(timeout=0.25)
+            if b is not None:
+                got.append(b)
+        # the epoch is in; the stalled copy now finishes with the session
+        # still serving, so a second delivery would reach the client
+        release.set()
+        time.sleep(1.0)
+        while (b := client.get_batch(timeout=0.25)) is not None:
+            got.append(b)
+    finally:
+        release.set()
+        sess.stop()
+    assert sum(want.values()) == 1024
+    assert _row_counts(got) == want
+    assert sess.state == "COMPLETED"
     assert sess.worker_metrics().rows_done == 1024
-    assert set(sess.master.checkpoint()["dispatches"].values()) == {1}
+    if stall == "wedged":
+        # the wedged split was re-dispatched once, the other never
+        assert sorted(sess.master.checkpoint()["dispatches"].values()) == [1, 2]
 
 
 def test_master_checkpoint_restore_resumes():
@@ -442,6 +486,27 @@ def test_heartbeat_extends_lease():
     assert m.failure_report() == []
     m.complete_split("slowpoke", s.split_id)
     assert m.finished and m.state == "COMPLETED"
+
+
+def test_claim_delivery_is_exactly_once():
+    from repro.core.dpp import SessionState
+
+    t = _table(n_partitions=1, rows=256)
+    spec = _spec(t)
+    rows = {p: t.partitions[p].num_rows for p in spec.partitions}
+    now = [0.0]
+    m = DPPMaster(spec, rows, lease_s=1.0, clock=lambda: now[0])
+    s = m.get_split("slow")
+    now[0] += 2.0                                   # slow's lease lapses
+    assert m.get_split("fast").split_id == s.split_id
+    assert m.claim_delivery("fast", s.split_id)
+    assert not m.claim_delivery("slow", s.split_id)     # fast is delivering
+    m.abandon_delivery("fast", s.split_id)          # fast stopped mid-delivery
+    assert m.get_split("fresh").split_id == s.split_id  # re-queued, not lost
+    assert m.claim_delivery("fresh", s.split_id)
+    m.complete_split("fresh", s.split_id)
+    assert not m.claim_delivery("slow", s.split_id)     # done: copy dropped
+    assert m.state == SessionState.COMPLETED
 
 
 def test_stale_report_from_superseded_dispatch_is_ignored():
