@@ -147,12 +147,12 @@ def run(quick: bool = False) -> None:
             repeat=repeat,
         )
         s = eng.stats
-        total = max(s.decrypt_s + s.decode_s + s.gather_s + s.assemble_s,
+        total = max(s.unpack_s + s.launch_s + s.fallback_s + s.assemble_s,
                     1e-12)
         emit(f"extract.stages_{tag}", us,
-             f"decrypt_pct={100 * s.decrypt_s / total:.0f} "
-             f"decode_pct={100 * s.decode_s / total:.0f} "
-             f"gather_pct={100 * s.gather_s / total:.0f} "
+             f"unpack_pct={100 * s.unpack_s / total:.0f} "
+             f"launch_pct={100 * s.launch_s / total:.0f} "
+             f"host_pct={100 * s.fallback_s / total:.0f} "
              f"assemble_pct={100 * s.assemble_s / total:.0f} "
              f"launches={s.kernel_launches // repeat}")
 

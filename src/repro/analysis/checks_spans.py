@@ -7,14 +7,16 @@ attribution.  Manually-paired ``__enter__``/``__exit__`` (or a handle
 stashed in a variable and closed "later") leaks exactly this way on any
 exception path.
 
-  * **S001** — in ``src/repro/core/**``, ``<tracer>.span(...)`` may only
-    appear as a ``with``-statement context expression, where the span is
-    closed on every exit path by construction.  The atomic APIs
-    (``record`` / ``instant``) are exempt — they never hold a span open.
+  * **S001** — in ``src/repro/core/**``, ``<tracer>.span(...)`` and
+    ``phase(<tracer>, ...)`` may only appear as a ``with``-statement
+    context expression, where the span is closed on every exit path by
+    construction.  The atomic APIs (``record`` / ``instant``) are exempt —
+    they never hold a span open.
 
-A call is recognized as a span-open when the receiver chain contains a
-``tracer``-named part (``self.tracer.span(...)``, ``tracer.span(...)``),
-so unrelated ``.span`` methods on other objects are not captured.
+A call is recognized as a span-open when the receiver chain (for
+``phase``, the first argument) contains a ``tracer``-named part
+(``self.tracer.span(...)``, ``tracer.span(...)``), so unrelated ``.span``
+methods on other objects are not captured.
 """
 from __future__ import annotations
 
@@ -36,13 +38,20 @@ S001 = rule("REPRO-S001",
 _SCOPE = "src/repro/core/"
 
 
-def _is_span_open(node: ast.AST) -> bool:
-    if not (isinstance(node, ast.Call)
-            and isinstance(node.func, ast.Attribute)
-            and node.func.attr == "span"):
-        return False
-    chain = attr_chain(node.func.value) or []
+def _names_tracer(node: ast.AST) -> bool:
+    chain = attr_chain(node) or []
     return any("tracer" in part.lower() for part in chain)
+
+
+def _is_span_open(node: ast.AST) -> bool:
+    if not isinstance(node, ast.Call):
+        return False
+    f = node.func
+    if isinstance(f, ast.Attribute) and f.attr == "span":
+        return _names_tracer(f.value)
+    if isinstance(f, ast.Name) and f.id == "phase" and node.args:
+        return _names_tracer(node.args[0])
+    return False
 
 
 class _Scan(ast.NodeVisitor):

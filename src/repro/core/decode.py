@@ -29,22 +29,26 @@ TensorCache entries stay engine-agnostic.  The differential suite
 (``tests/test_decode.py``) pins the parity on the adversarial matrix.
 
 ``DecodeStats`` feeds ``WorkerMetrics`` (``extract_fused_s`` /
-``extract_fallback_s`` / ``decode_launches``) and carries a
-Table-9-style stage split (decrypt / decode / gather / assemble) for
-``benchmarks/bench_extract.py``.
+``extract_fallback_s`` / ``decode_launches``) and splits the stripe's
+decode seconds into phases that do not overlap, each also traced as a
+span: ``unpack_s`` (``extract.unpack``: host decompress, header parse,
+classification and packing of the launch operands), ``launch_s``
+(``kernel.<name>``: one Pallas launch from its first host-to-device copy
+to its result back on the host), ``fallback_s`` (``extract.host``: the
+per-stream host decode) and ``assemble_s`` (``extract.assemble``: the
+``ColumnBatch``).
 """
 from __future__ import annotations
 
 import dataclasses
 import struct
-import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
 from repro.core import dwrf
 from repro.core.schema import ColumnBatch, SparseColumn
-from repro.obs import counter
+from repro.obs import NULL_TRACER, counter, phase
 
 _U8 = np.dtype(np.uint8)
 _I8 = np.dtype("<i8")
@@ -61,11 +65,11 @@ class DecodeStats:
     kernel_launches: int = counter()   # fused launches + per-feature host calls
     fused_launches: int = counter()    # batched kernel launches alone
     fused_s: float = counter(0.0)      # extract_s attribution: batched path
-    fallback_s: float = counter(0.0)   # extract_s attribution: per-stream path
-    # Table-9-style stage split (§6.3): the four sum to ~total decode time
-    decrypt_s: float = counter(0.0)    # XOR byte pass
-    decode_s: float = counter(0.0)     # decompress + header parse + dense unpack
-    gather_s: float = counter(0.0)     # sparse/map array extraction
+    # the decode seconds by phase (§6.3): the four sum to the decode time,
+    # and fused_s = unpack_s + launch_s + the batched path's assemble_s
+    unpack_s: float = counter(0.0)     # host decompress, parse, operand packing
+    launch_s: float = counter(0.0)     # Pallas launches, copy in to copy out
+    fallback_s: float = counter(0.0)   # per-stream host decode
     assemble_s: float = counter(0.0)   # ColumnBatch construction
 
 
@@ -138,6 +142,7 @@ class DecodeEngine:
     """Decodes one stripe's fetched stream bytes into a ``ColumnBatch``."""
 
     name = "base"
+    tracer = NULL_TRACER        # the owning reader's, for the phase spans
 
     def __init__(self):
         self.stats = DecodeStats()
@@ -168,37 +173,25 @@ class NumpyDecodeEngine(DecodeEngine):
         sparse: Dict[int, SparseColumn] = {}
         labels = None
         want = set(feature_ids)
-        for s in stripe.streams:
-            key = (s.fid, s.kind)
-            if key not in fetch:
-                continue
-            t0 = time.perf_counter()
-            codec, body = dwrf.split_stream(fetch[key])
-            plain = dwrf._decrypt(body)
-            t1 = time.perf_counter()
-            payload = codec.decompress(plain)
-            t2 = time.perf_counter()
-            lab, n_feats = _decode_payload(
-                s.kind, s.fid, payload, stripe.num_rows, want, dense, sparse
+        with phase(self.tracer, "extract.host", st, "fallback_s"):
+            for s in stripe.streams:
+                key = (s.fid, s.kind)
+                if key not in fetch:
+                    continue
+                codec, body = dwrf.split_stream(fetch[key])
+                payload = codec.decompress(dwrf._decrypt(body))
+                lab, n_feats = _decode_payload(
+                    s.kind, s.fid, payload, stripe.num_rows, want, dense, sparse
+                )
+                if lab is not None:
+                    labels = lab
+                st.fallback_streams += 1
+                st.kernel_launches += 1 + n_feats
+        with phase(self.tracer, "extract.assemble", st, "assemble_s"):
+            return ColumnBatch(
+                num_rows=stripe.num_rows, dense=dense, sparse=sparse,
+                labels=labels,
             )
-            if lab is not None:
-                labels = lab
-            t3 = time.perf_counter()
-            st.decrypt_s += t1 - t0
-            if s.kind in ("sparse", "sparse_map"):
-                st.decode_s += t2 - t1
-                st.gather_s += t3 - t2
-            else:
-                st.decode_s += t3 - t1
-            st.fallback_s += t3 - t0
-            st.fallback_streams += 1
-            st.kernel_launches += 1 + n_feats
-        t4 = time.perf_counter()
-        batch = ColumnBatch(
-            num_rows=stripe.num_rows, dense=dense, sparse=sparse, labels=labels
-        )
-        st.assemble_s += time.perf_counter() - t4
-        return batch
 
 
 class PallasDecodeEngine(DecodeEngine):
@@ -220,6 +213,15 @@ class PallasDecodeEngine(DecodeEngine):
         self.use_pallas = use_pallas
 
     # -- fused launches -----------------------------------------------------
+    # Each launch is one ``kernel.<name>`` phase: the operands' copies to
+    # the device, the dispatch, the wait and the copy back (``np.asarray``
+    # waits for the result).  Operand packing is the caller's, in unpack.
+
+    _LAUNCH = ("launch_s", "fused_s")     # the counters a launch adds to
+
+    def _count_launch(self) -> None:
+        self.stats.kernel_launches += 1
+        self.stats.fused_launches += 1
 
     def _xor(self, buf: np.ndarray, n: int) -> np.ndarray:
         """One fused decrypt launch over the stripe's concatenated stream
@@ -233,14 +235,21 @@ class PallasDecodeEngine(DecodeEngine):
         from repro.kernels import ops as kops
 
         words = buf.view("<i4").reshape(-1, 128)
-        out = kops.xor_decrypt(jnp.asarray(words), use_pallas=self.use_pallas)
-        self.stats.kernel_launches += 1
-        self.stats.fused_launches += 1
-        return np.asarray(out).reshape(-1).view(np.uint8)[:n]
+        self._count_launch()
+        with phase(self.tracer, "kernel.xor_decrypt", self.stats, *self._LAUNCH):
+            out = kops.xor_decrypt(jnp.asarray(words), use_pallas=self.use_pallas)
+            return np.asarray(out).reshape(-1).view(np.uint8)[:n]
 
-    def _dense_launch(
-        self, rows: int, bm: np.ndarray, vals_list: List[np.ndarray]
-    ) -> List[np.ndarray]:
+    @staticmethod
+    def _dense_values(vals_list: List[np.ndarray]) -> np.ndarray:
+        """Every dense feature's present value bits, one padded row each."""
+        cap = max(max(len(v) for v in vals_list), 1)
+        vals = np.zeros((len(vals_list), cap), np.int32)
+        for j, v in enumerate(vals_list):
+            vals[j, : len(v)] = v
+        return vals
+
+    def _dense_launch(self, bm: np.ndarray, vals: np.ndarray) -> np.ndarray:
         """One launch for every dense feature: features-major bitmap words
         (``bm``, already packed (F, 4*words) uint8 by the caller) + value
         bit patterns in, f32 bits (NaN where absent) out."""
@@ -248,30 +257,22 @@ class PallasDecodeEngine(DecodeEngine):
 
         from repro.kernels import ops as kops
 
-        cap = max(max(len(v) for v in vals_list), 1)
-        vals = np.zeros((len(vals_list), cap), np.int32)
-        for j, v in enumerate(vals_list):
-            vals[j, : len(v)] = v
-        out = kops.dense_unpack(
-            jnp.asarray(bm.view("<i4")), jnp.asarray(vals),
-            use_pallas=self.use_pallas,
-        )
-        self.stats.kernel_launches += 1
-        self.stats.fused_launches += 1
-        res = np.asarray(out)
-        return [res[j, :rows].view(np.float32) for j in range(len(vals_list))]
+        self._count_launch()
+        with phase(self.tracer, "kernel.dense_unpack", self.stats, *self._LAUNCH):
+            out = kops.dense_unpack(
+                jnp.asarray(bm.view("<i4")), jnp.asarray(vals),
+                use_pallas=self.use_pallas,
+            )
+            return np.asarray(out)
 
-    def _gather_launch(
-        self,
+    @staticmethod
+    def _gather_operands(
         pool: List[bytes],
         requests: List[Tuple[int, np.dtype, int, int]],
-    ) -> List[np.ndarray]:
-        """One launch for every requested array region: splice the
-        byte-unaligned regions out of the concatenated payload words."""
-        import jax.numpy as jnp
-
-        from repro.kernels import ops as kops
-
+    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+        """The gather's operands for every requested array region: the
+        concatenated payload words, each output word's source word and bit
+        shift, and each region's first output word."""
         base = np.zeros(len(pool), np.int64)
         pos = 0
         for i, b in enumerate(pool):
@@ -304,19 +305,24 @@ class PallasDecodeEngine(DecodeEngine):
                     - np.repeat(out_off[:-1], slots))
             idx[:total] = ((ab // 4)[req] + lane).astype(np.int32)
             shift[:total] = ((ab % 4) * 8)[req].astype(np.int32)
-        out = kops.ragged_gather(
-            jnp.asarray(src.view("<i4").reshape(s_rows, 128)),
-            jnp.asarray(idx.reshape(m_rows, 128)),
-            jnp.asarray(shift.reshape(m_rows, 128)),
-            use_pallas=self.use_pallas,
-        )
-        self.stats.kernel_launches += 1
-        self.stats.fused_launches += 1
-        flat = np.ascontiguousarray(np.asarray(out).reshape(-1))
-        return [
-            np.frombuffer(flat, dt, nb // dt.itemsize, int(out_off[r]) * 4)
-            for r, (_, dt, _, nb) in enumerate(requests)
-        ]
+        return (src.view("<i4").reshape(s_rows, 128), idx.reshape(m_rows, 128),
+                shift.reshape(m_rows, 128), out_off)
+
+    def _gather_launch(self, src: np.ndarray, idx: np.ndarray,
+                       shift: np.ndarray) -> np.ndarray:
+        """One launch for every requested array region: splice the
+        byte-unaligned regions out of the concatenated payload words."""
+        import jax.numpy as jnp
+
+        from repro.kernels import ops as kops
+
+        self._count_launch()
+        with phase(self.tracer, "kernel.ragged_gather", self.stats, *self._LAUNCH):
+            out = kops.ragged_gather(
+                jnp.asarray(src), jnp.asarray(idx), jnp.asarray(shift),
+                use_pallas=self.use_pallas,
+            )
+            return np.ascontiguousarray(np.asarray(out).reshape(-1))
 
     # -- stripe decode ------------------------------------------------------
 
@@ -334,296 +340,295 @@ class PallasDecodeEngine(DecodeEngine):
         # codec byte is read from the *original* buffer and its decrypted
         # garbage twin in ``plain`` is simply never referenced (XOR is
         # byte-position-local, so everything after it decrypts exactly).
-        t0 = time.perf_counter()
-        codecs = dwrf._CODECS
-        entries: List[Tuple[dwrf.StreamInfo, dwrf.Codec, int, int]] = []
-        parts: List[bytes] = []
-        pos = 0
-        for s in stripe.streams:
-            key = (s.fid, s.kind)
-            data = fetch.get(key)
-            if data is None:
-                continue
-            codec = codecs.get(data[0])
-            if codec is None:
-                dwrf.split_stream(data)      # raises the reference KeyError
-            entries.append((s, codec, pos + 1, len(data) - 1))
-            parts.append(data)
-            pos += len(data)
-        if not entries:
-            return ColumnBatch(num_rows=rows, dense={}, sparse={}, labels=None)
-        buf = np.zeros(pos + (-pos) % 512, np.uint8)
-        mv = memoryview(buf)                 # C-level memcpy per stream
-        at = 0
-        for d in parts:
-            ln = len(d)
-            mv[at: at + ln] = d
-            at += ln
-        plain = self._xor(buf, pos)
-        t1 = time.perf_counter()
-        st.decrypt_s += t1 - t0
-        st.fused_s += t1 - t0
-
-        # phase 2 — host decompress + header parse + classification.
-        # ``tokens`` records, in stream order, which fids each stream
-        # contributes and through which path: the reference inserts dict
-        # keys in stream order, so assembly must replay that order even
-        # when fused and demoted streams interleave.
-        #   ["f", dense_fids, sparse_fids]  — fused stream
-        #   ["h", host_job_index]           — host-fallback stream
-        dense_jobs: List[list] = []   # [fid, packed, vals, payload, s, tok, oi]
-        pool: List[bytes] = []
-        requests: List[Tuple[int, np.dtype, int, int]] = []
-        dense_sinks: List[Tuple[int, int]] = []              # (fid, req)
-        sparse_sinks: List[Tuple[int, int, int, Optional[int]]] = []
-        host_jobs: List[list] = []           # [stream_order, StreamInfo, payload]
-        tokens: List[list] = []
-
-        def _req(pi: int, hdr: Tuple[np.dtype, int, int]) -> int:
-            requests.append((pi, hdr[0], hdr[1], hdr[2]))
-            return len(requests) - 1
-
-        t2 = time.perf_counter()
-        headers = dwrf.packed_array_headers
-        fro = np.frombuffer
-
-        # vectorized prepass: flattened dense streams under the raw codec
-        # share one fixed ``_pack_arrays`` header template (only the
-        # value-byte count differs), so template match, length check, and
-        # presence-bitmap extraction run as whole-stripe numpy gathers
-        # instead of per-stream header walks.  Anything that misses the
-        # template falls through to the generic per-stream classification
-        # below — same decision, slower route.
-        fast: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
-        if rows > 0:
-            nb1 = -(-rows // 8)
-            head = struct.pack("<II", 2, 3) + b"|u1" + struct.pack("<Q", nb1)
-            mid = struct.pack("<I", 3) + b"<f4"
-            cands = [
-                (oi, start, ln)
-                for oi, (s, codec, start, ln) in enumerate(entries)
-                if s.kind == "dense" and codec.cid == 0
-                and s.fid in want and ln >= 34 + nb1
-            ]
-            if cands:
-                starts = np.array([c[1] for c in cands], np.int64)
-                lns = np.array([c[2] for c in cands], np.int64)
-                okh = (plain[starts[:, None] + np.arange(19)]
-                       == fro(head, np.uint8)).all(1)
-                okm = (plain[starts[:, None] + (19 + nb1) + np.arange(7)]
-                       == fro(mid, np.uint8)).all(1)
-                nb2 = np.ascontiguousarray(
-                    plain[starts[:, None] + (26 + nb1) + np.arange(8)]
-                ).view("<u8")[:, 0].astype(np.int64)
-                ok = okh & okm & (nb2 % 4 == 0) & (lns == 34 + nb1 + nb2)
-                if ok.any():
-                    sel = np.flatnonzero(ok)
-                    bmat = plain[starts[sel, None] + 19 + np.arange(nb1)]
-                    for k, ci in enumerate(sel):
-                        oi, s0, _ = cands[int(ci)]
-                        fast[oi] = (
-                            bmat[k],
-                            fro(plain, "<i4", int(nb2[ci]) // 4,
-                                s0 + 34 + nb1),
-                        )
-
-        for oi, (s, codec, start, ln) in enumerate(entries):
-            payload = plain[start: start + ln]
-            if codec.cid:                     # raw (cid 0) is the identity
-                payload = codec.decompress(payload)
-            demote = False
-            if s.kind == "dense" and s.fid in want:
-                fv = fast.get(oi)
-                if fv is not None:
-                    dense_jobs.append([
-                        s.fid, fv[0], fv[1], payload, s, len(tokens), oi,
-                    ])
-                    tokens.append(["f", [s.fid], ()])
+        unpack = ("extract.unpack", st, "unpack_s", "fused_s")
+        with phase(self.tracer, *unpack):
+            codecs = dwrf._CODECS
+            entries: List[Tuple[dwrf.StreamInfo, dwrf.Codec, int, int]] = []
+            parts: List[bytes] = []
+            pos = 0
+            for s in stripe.streams:
+                key = (s.fid, s.kind)
+                data = fetch.get(key)
+                if data is None:
                     continue
-                ok = rows > 0
-                if ok:
+                codec = codecs.get(data[0])
+                if codec is None:
+                    dwrf.split_stream(data)      # raises the reference KeyError
+                entries.append((s, codec, pos + 1, len(data) - 1))
+                parts.append(data)
+                pos += len(data)
+            if not entries:
+                return ColumnBatch(num_rows=rows, dense={}, sparse={}, labels=None)
+            buf = np.zeros(pos + (-pos) % 512, np.uint8)
+            mv = memoryview(buf)                 # C-level memcpy per stream
+            at = 0
+            for d in parts:
+                ln = len(d)
+                mv[at: at + ln] = d
+                at += ln
+        plain = self._xor(buf, pos)
+
+        with phase(self.tracer, *unpack):
+            # phase 2 — host decompress + header parse + classification.
+            # ``tokens`` records, in stream order, which fids each stream
+            # contributes and through which path: the reference inserts dict
+            # keys in stream order, so assembly must replay that order even
+            # when fused and demoted streams interleave.
+            #   ["f", dense_fids, sparse_fids]  — fused stream
+            #   ["h", host_job_index]           — host-fallback stream
+            dense_jobs: List[list] = []   # [fid, packed, vals, payload, s, tok, oi]
+            pool: List[bytes] = []
+            requests: List[Tuple[int, np.dtype, int, int]] = []
+            dense_sinks: List[Tuple[int, int]] = []              # (fid, req)
+            sparse_sinks: List[Tuple[int, int, int, Optional[int]]] = []
+            host_jobs: List[list] = []           # [stream_order, StreamInfo, payload]
+            tokens: List[list] = []
+
+            def _req(pi: int, hdr: Tuple[np.dtype, int, int]) -> int:
+                requests.append((pi, hdr[0], hdr[1], hdr[2]))
+                return len(requests) - 1
+
+            headers = dwrf.packed_array_headers
+            fro = np.frombuffer
+
+            # vectorized prepass: flattened dense streams under the raw codec
+            # share one fixed ``_pack_arrays`` header template (only the
+            # value-byte count differs), so template match, length check, and
+            # presence-bitmap extraction run as whole-stripe numpy gathers
+            # instead of per-stream header walks.  Anything that misses the
+            # template falls through to the generic per-stream classification
+            # below — same decision, slower route.
+            fast: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
+            if rows > 0:
+                nb1 = -(-rows // 8)
+                head = struct.pack("<II", 2, 3) + b"|u1" + struct.pack("<Q", nb1)
+                mid = struct.pack("<I", 3) + b"<f4"
+                cands = [
+                    (oi, start, ln)
+                    for oi, (s, codec, start, ln) in enumerate(entries)
+                    if s.kind == "dense" and codec.cid == 0
+                    and s.fid in want and ln >= 34 + nb1
+                ]
+                if cands:
+                    starts = np.array([c[1] for c in cands], np.int64)
+                    lns = np.array([c[2] for c in cands], np.int64)
+                    okh = (plain[starts[:, None] + np.arange(19)]
+                           == fro(head, np.uint8)).all(1)
+                    okm = (plain[starts[:, None] + (19 + nb1) + np.arange(7)]
+                           == fro(mid, np.uint8)).all(1)
+                    nb2 = np.ascontiguousarray(
+                        plain[starts[:, None] + (26 + nb1) + np.arange(8)]
+                    ).view("<u8")[:, 0].astype(np.int64)
+                    ok = okh & okm & (nb2 % 4 == 0) & (lns == 34 + nb1 + nb2)
+                    if ok.any():
+                        sel = np.flatnonzero(ok)
+                        bmat = plain[starts[sel, None] + 19 + np.arange(nb1)]
+                        for k, ci in enumerate(sel):
+                            oi, s0, _ = cands[int(ci)]
+                            fast[oi] = (
+                                bmat[k],
+                                fro(plain, "<i4", int(nb2[ci]) // 4,
+                                    s0 + 34 + nb1),
+                            )
+
+            for oi, (s, codec, start, ln) in enumerate(entries):
+                payload = plain[start: start + ln]
+                if codec.cid:                     # raw (cid 0) is the identity
+                    payload = codec.decompress(payload)
+                demote = False
+                if s.kind == "dense" and s.fid in want:
+                    fv = fast.get(oi)
+                    if fv is not None:
+                        dense_jobs.append([
+                            s.fid, fv[0], fv[1], payload, s, len(tokens), oi,
+                        ])
+                        tokens.append(["f", [s.fid], ()])
+                        continue
+                    ok = rows > 0
+                    if ok:
+                        hdrs = headers(payload)
+                        ok = (
+                            len(hdrs) == 2
+                            and hdrs[0][0] == _U8 and hdrs[1][0] == _F4
+                            and hdrs[1][2] % 4 == 0
+                            and hdrs[0][2] * 8 >= rows
+                        )
+                    if ok:
+                        # the presence-popcount == len(values) precondition of
+                        # the reference scatter is validated vectorized across
+                        # all dense jobs after this loop
+                        dense_jobs.append([
+                            s.fid,
+                            fro(payload, np.uint8, hdrs[0][2], hdrs[0][1]),
+                            fro(payload, "<i4", hdrs[1][2] // 4, hdrs[1][1]),
+                            payload, s, len(tokens), oi,
+                        ])
+                        tokens.append(["f", [s.fid], ()])
+                    else:
+                        demote = True
+                elif s.kind == "sparse" and s.fid in want:
                     hdrs = headers(payload)
                     ok = (
-                        len(hdrs) == 2
-                        and hdrs[0][0] == _U8 and hdrs[1][0] == _F4
-                        and hdrs[1][2] % 4 == 0
-                        and hdrs[0][2] * 8 >= rows
+                        len(hdrs) in (2, 3)
+                        and hdrs[0][0] == _I8 and hdrs[1][0] == _I8
+                        and (len(hdrs) == 2 or hdrs[2][0] == _F4)
                     )
-                if ok:
-                    # the presence-popcount == len(values) precondition of
-                    # the reference scatter is validated vectorized across
-                    # all dense jobs after this loop
-                    dense_jobs.append([
-                        s.fid,
-                        fro(payload, np.uint8, hdrs[0][2], hdrs[0][1]),
-                        fro(payload, "<i4", hdrs[1][2] // 4, hdrs[1][1]),
-                        payload, s, len(tokens), oi,
-                    ])
-                    tokens.append(["f", [s.fid], ()])
-                else:
-                    demote = True
-            elif s.kind == "sparse" and s.fid in want:
-                hdrs = headers(payload)
-                ok = (
-                    len(hdrs) in (2, 3)
-                    and hdrs[0][0] == _I8 and hdrs[1][0] == _I8
-                    and (len(hdrs) == 2 or hdrs[2][0] == _F4)
-                )
-                if ok:
-                    pi = len(pool)
-                    pool.append(payload)
-                    sparse_sinks.append((
-                        s.fid, _req(pi, hdrs[0]), _req(pi, hdrs[1]),
-                        _req(pi, hdrs[2]) if len(hdrs) == 3 else None,
-                    ))
-                    tokens.append(["f", (), [s.fid]])
-                    st.fused_streams += 1
-                else:
-                    demote = True
-            elif s.kind == "dense_map":
-                hdrs = headers(payload)
-                ok = len(hdrs) >= 1 and hdrs[0][0] == _I8
-                if ok:
-                    fids = fro(payload, _I8, hdrs[0][2] // 8, hdrs[0][1])
-                    wanted = [
-                        (i, int(f)) for i, f in enumerate(fids) if f in want
-                    ]
-                    ok = len(hdrs) == 1 + len(fids) and all(
-                        hdrs[1 + i][0] == _F4 for i, _ in wanted
-                    )
-                if ok:
-                    pi = len(pool)
-                    pool.append(payload)
-                    for i, f in wanted:
-                        dense_sinks.append((f, _req(pi, hdrs[1 + i])))
-                    tokens.append(["f", [f for _, f in wanted], ()])
-                    st.fused_streams += 1
-                else:
-                    demote = True
-            elif s.kind == "sparse_map":
-                hdrs = headers(payload)
-
-                def _meta(i: int) -> np.ndarray:
-                    dt, off, nb = hdrs[i]
-                    return fro(payload, dt, nb // dt.itemsize, off)
-
-                ok = len(hdrs) >= 1
-                wanted = []
-                flags = None
-                if ok:
-                    a0 = _meta(0)
-                    v2 = (
-                        a0.size == 1 and a0.dtype.kind == "i"
-                        and int(a0[0]) == dwrf.SPARSE_MAP_V2
-                    )
-                    if v2 and len(hdrs) >= 3:
-                        fids, flags, base = _meta(1), _meta(2), 3
-                    elif not v2:
-                        fids, base = a0, 1
-                    else:
-                        ok = False
-                if ok:
-                    ok = len(hdrs) == base + 3 * len(fids)
-                if ok:
-                    wanted = [
-                        (i, int(f)) for i, f in enumerate(fids) if f in want
-                    ]
-                    ok = all(
-                        hdrs[base + 3 * i][0] == _I8
-                        and hdrs[base + 1 + 3 * i][0] == _I8
-                        and hdrs[base + 2 + 3 * i][0] == _F4
-                        for i, _ in wanted
-                    )
-                if ok:
-                    pi = len(pool)
-                    pool.append(payload)
-                    for i, f in wanted:
-                        has_scores = (
-                            bool(flags[i]) if flags is not None
-                            else hdrs[base + 2 + 3 * i][2] > 0
-                        )
+                    if ok:
+                        pi = len(pool)
+                        pool.append(payload)
                         sparse_sinks.append((
-                            f,
-                            _req(pi, hdrs[base + 3 * i]),
-                            _req(pi, hdrs[base + 1 + 3 * i]),
-                            _req(pi, hdrs[base + 2 + 3 * i])
-                            if has_scores else None,
+                            s.fid, _req(pi, hdrs[0]), _req(pi, hdrs[1]),
+                            _req(pi, hdrs[2]) if len(hdrs) == 3 else None,
                         ))
-                    tokens.append(["f", (), [f for _, f in wanted]])
-                    st.fused_streams += 1
-                else:
-                    demote = True
-            elif s.kind == "labels":
-                tokens.append(["h", len(host_jobs)])
-                host_jobs.append([oi, s, payload])
-                continue
-            else:
-                # unwanted flattened streams / unknown kinds: decompressed
-                # (like the reference) with nothing left to batch
-                st.fused_streams += 1
-                continue
-            if demote:
-                st.demoted_streams += 1
-                tokens.append(["h", len(host_jobs)])
-                host_jobs.append([oi, s, payload])
+                        tokens.append(["f", (), [s.fid]])
+                        st.fused_streams += 1
+                    else:
+                        demote = True
+                elif s.kind == "dense_map":
+                    hdrs = headers(payload)
+                    ok = len(hdrs) >= 1 and hdrs[0][0] == _I8
+                    if ok:
+                        fids = fro(payload, _I8, hdrs[0][2] // 8, hdrs[0][1])
+                        wanted = [
+                            (i, int(f)) for i, f in enumerate(fids) if f in want
+                        ]
+                        ok = len(hdrs) == 1 + len(fids) and all(
+                            hdrs[1 + i][0] == _F4 for i, _ in wanted
+                        )
+                    if ok:
+                        pi = len(pool)
+                        pool.append(payload)
+                        for i, f in wanted:
+                            dense_sinks.append((f, _req(pi, hdrs[1 + i])))
+                        tokens.append(["f", [f for _, f in wanted], ()])
+                        st.fused_streams += 1
+                    else:
+                        demote = True
+                elif s.kind == "sparse_map":
+                    hdrs = headers(payload)
 
-        # vectorized precondition check over all dense jobs: the reference
-        # scatter needs popcount(presence[:rows]) == len(values) per
-        # feature — violations demote to host, which raises the reference
-        # error at that stream's position.  Dense jobs only count toward
-        # fused_streams once they survive this check (counters are
-        # monotonic; no increment-then-undo).
-        if dense_jobs:
-            nb = -(-rows // 8)
-            nw4 = ((nb + 3) // 4) * 4
-            bm = np.zeros((len(dense_jobs), nw4), np.uint8)
-            for j, job in enumerate(dense_jobs):
-                bm[j, :nb] = job[1][:nb]
-            pops = np.unpackbits(bm[:, :nb], axis=1, count=rows).sum(
-                axis=1, dtype=np.int64
-            )
-            bad = [
-                j for j, job in enumerate(dense_jobs)
-                if int(pops[j]) != len(job[2])
-            ]
-            if bad:
-                for j in bad:
-                    fid, _, _, payload, s, ti, oi = dense_jobs[j]
-                    st.demoted_streams += 1
-                    tokens[ti] = ["h", len(host_jobs)]
+                    def _meta(i: int) -> np.ndarray:
+                        dt, off, nb = hdrs[i]
+                        return fro(payload, dt, nb // dt.itemsize, off)
+
+                    ok = len(hdrs) >= 1
+                    wanted = []
+                    flags = None
+                    if ok:
+                        a0 = _meta(0)
+                        v2 = (
+                            a0.size == 1 and a0.dtype.kind == "i"
+                            and int(a0[0]) == dwrf.SPARSE_MAP_V2
+                        )
+                        if v2 and len(hdrs) >= 3:
+                            fids, flags, base = _meta(1), _meta(2), 3
+                        elif not v2:
+                            fids, base = a0, 1
+                        else:
+                            ok = False
+                    if ok:
+                        ok = len(hdrs) == base + 3 * len(fids)
+                    if ok:
+                        wanted = [
+                            (i, int(f)) for i, f in enumerate(fids) if f in want
+                        ]
+                        ok = all(
+                            hdrs[base + 3 * i][0] == _I8
+                            and hdrs[base + 1 + 3 * i][0] == _I8
+                            and hdrs[base + 2 + 3 * i][0] == _F4
+                            for i, _ in wanted
+                        )
+                    if ok:
+                        pi = len(pool)
+                        pool.append(payload)
+                        for i, f in wanted:
+                            has_scores = (
+                                bool(flags[i]) if flags is not None
+                                else hdrs[base + 2 + 3 * i][2] > 0
+                            )
+                            sparse_sinks.append((
+                                f,
+                                _req(pi, hdrs[base + 3 * i]),
+                                _req(pi, hdrs[base + 1 + 3 * i]),
+                                _req(pi, hdrs[base + 2 + 3 * i])
+                                if has_scores else None,
+                            ))
+                        tokens.append(["f", (), [f for _, f in wanted]])
+                        st.fused_streams += 1
+                    else:
+                        demote = True
+                elif s.kind == "labels":
+                    tokens.append(["h", len(host_jobs)])
                     host_jobs.append([oi, s, payload])
-                keep = [
-                    j for j in range(len(dense_jobs)) if j not in set(bad)
-                ]
-                dense_jobs = [dense_jobs[j] for j in keep]
-                bm = bm[keep]
-            st.fused_streams += len(dense_jobs)
-        t3 = time.perf_counter()
-        st.decode_s += t3 - t2
-        st.fused_s += t3 - t2
+                    continue
+                else:
+                    # unwanted flattened streams / unknown kinds: decompressed
+                    # (like the reference) with nothing left to batch
+                    st.fused_streams += 1
+                    continue
+                if demote:
+                    st.demoted_streams += 1
+                    tokens.append(["h", len(host_jobs)])
+                    host_jobs.append([oi, s, payload])
 
-        # phase 3 — the two batched launches
-        if dense_jobs:
-            t4 = time.perf_counter()
-            cols = self._dense_launch(rows, bm, [j[2] for j in dense_jobs])
-            for job, col in zip(dense_jobs, cols):
-                dense[job[0]] = col
-            dt = time.perf_counter() - t4
-            st.decode_s += dt
-            st.fused_s += dt
-        if requests:
-            t5 = time.perf_counter()
-            arrs = self._gather_launch(pool, requests)
-            for fid, ri in dense_sinks:
-                dense[fid] = arrs[ri]
-            for fid, oi, vi, si in sparse_sinks:
-                sparse[fid] = SparseColumn(
-                    offsets=arrs[oi], values=arrs[vi],
-                    scores=arrs[si] if si is not None else None,
+            # vectorized precondition check over all dense jobs: the reference
+            # scatter needs popcount(presence[:rows]) == len(values) per
+            # feature — violations demote to host, which raises the reference
+            # error at that stream's position.  Dense jobs only count toward
+            # fused_streams once they survive this check (counters are
+            # monotonic; no increment-then-undo).
+            if dense_jobs:
+                nb = -(-rows // 8)
+                nw4 = ((nb + 3) // 4) * 4
+                bm = np.zeros((len(dense_jobs), nw4), np.uint8)
+                for j, job in enumerate(dense_jobs):
+                    bm[j, :nb] = job[1][:nb]
+                pops = np.unpackbits(bm[:, :nb], axis=1, count=rows).sum(
+                    axis=1, dtype=np.int64
                 )
-            dt = time.perf_counter() - t5
-            st.gather_s += dt
-            st.fused_s += dt
+                bad = [
+                    j for j, job in enumerate(dense_jobs)
+                    if int(pops[j]) != len(job[2])
+                ]
+                if bad:
+                    for j in bad:
+                        fid, _, _, payload, s, ti, oi = dense_jobs[j]
+                        st.demoted_streams += 1
+                        tokens[ti] = ["h", len(host_jobs)]
+                        host_jobs.append([oi, s, payload])
+                    keep = [
+                        j for j in range(len(dense_jobs)) if j not in set(bad)
+                    ]
+                    dense_jobs = [dense_jobs[j] for j in keep]
+                    bm = bm[keep]
+                st.fused_streams += len(dense_jobs)
+            # the launches' operands
+            if dense_jobs:
+                vals = self._dense_values([j[2] for j in dense_jobs])
+            if requests:
+                src, idx, shift, out_off = self._gather_operands(pool, requests)
+
+        # phase 3 — the two batched launches, then their results in place
+        if dense_jobs:
+            dense_bits = self._dense_launch(bm, vals)
+        if requests:
+            flat = self._gather_launch(src, idx, shift)
+        assemble = ("extract.assemble", st, "assemble_s", "fused_s")
+        with phase(self.tracer, *assemble):
+            for j, job in enumerate(dense_jobs):
+                dense[job[0]] = dense_bits[j, :rows].view(np.float32)
+            if requests:
+                arrs = [
+                    np.frombuffer(flat, dt, nb // dt.itemsize, int(out_off[r]) * 4)
+                    for r, (_, dt, _, nb) in enumerate(requests)
+                ]
+                for fid, ri in dense_sinks:
+                    dense[fid] = arrs[ri]
+                for fid, oi, vi, si in sparse_sinks:
+                    sparse[fid] = SparseColumn(
+                        offsets=arrs[oi], values=arrs[vi],
+                        scores=arrs[si] if si is not None else None,
+                    )
 
         # phase 4 — per-stream host fallback (labels + demoted streams),
         # processed in stream order so any reference error raises at the
@@ -632,55 +637,45 @@ class PallasDecodeEngine(DecodeEngine):
         # reference decoder wants real bytes (``io.BytesIO`` reads).
         added: List[Optional[Tuple[List[int], List[int]]]] = \
             [None] * len(host_jobs)
-        for ji in sorted(range(len(host_jobs)),
-                         key=lambda i: host_jobs[i][0]):
-            _, s, payload = host_jobs[ji]
-            t6 = time.perf_counter()
-            if not isinstance(payload, bytes):
-                payload = bytes(payload)
-            before_d, before_s = set(dense), set(sparse)
-            lab, n_feats = _decode_payload(
-                s.kind, s.fid, payload, rows, want, dense, sparse
-            )
-            if lab is not None:
-                labels = lab
-            added[ji] = (
-                [f for f in dense if f not in before_d],
-                [f for f in sparse if f not in before_s],
-            )
-            dt = time.perf_counter() - t6
-            if s.kind in ("sparse", "sparse_map"):
-                st.gather_s += dt
-            else:
-                st.decode_s += dt
-            st.fallback_s += dt
-            st.fallback_streams += 1
-            st.kernel_launches += n_feats
+        with phase(self.tracer, "extract.host", st, "fallback_s"):
+            for ji in sorted(range(len(host_jobs)),
+                             key=lambda i: host_jobs[i][0]):
+                _, s, payload = host_jobs[ji]
+                if not isinstance(payload, bytes):
+                    payload = bytes(payload)
+                before_d, before_s = set(dense), set(sparse)
+                lab, n_feats = _decode_payload(
+                    s.kind, s.fid, payload, rows, want, dense, sparse
+                )
+                if lab is not None:
+                    labels = lab
+                added[ji] = (
+                    [f for f in dense if f not in before_d],
+                    [f for f in sparse if f not in before_s],
+                )
+                st.fallback_streams += 1
+                st.kernel_launches += n_feats
 
         # phase 5 — assemble by replaying the reference's stream-order
         # dict insertion from the tokens
-        t7 = time.perf_counter()
-        dense_order: List[int] = []
-        sparse_order: List[int] = []
-        for tok in tokens:
-            if tok[0] == "f":
-                dense_order += tok[1]
-                sparse_order += tok[2]
-            else:
-                a = added[tok[1]]
-                if a is not None:
-                    dense_order += a[0]
-                    sparse_order += a[1]
-        batch = ColumnBatch(
-            num_rows=rows,
-            dense={f: dense[f] for f in dense_order if f in dense},
-            sparse={f: sparse[f] for f in sparse_order if f in sparse},
-            labels=labels,
-        )
-        dt = time.perf_counter() - t7
-        st.assemble_s += dt
-        st.fused_s += dt
-        return batch
+        with phase(self.tracer, *assemble):
+            dense_order: List[int] = []
+            sparse_order: List[int] = []
+            for tok in tokens:
+                if tok[0] == "f":
+                    dense_order += tok[1]
+                    sparse_order += tok[2]
+                else:
+                    a = added[tok[1]]
+                    if a is not None:
+                        dense_order += a[0]
+                        sparse_order += a[1]
+            return ColumnBatch(
+                num_rows=rows,
+                dense={f: dense[f] for f in dense_order if f in dense},
+                sparse={f: sparse[f] for f in sparse_order if f in sparse},
+                labels=labels,
+            )
 
 
 DECODE_ENGINES = {"numpy": NumpyDecodeEngine, "pallas": PallasDecodeEngine}

@@ -110,55 +110,53 @@ class DPPClient:
                 SessionState.FAILED, self.master.failure_report()
             )
 
+    def _sweep(self) -> Optional[Dict[str, np.ndarray]]:
+        """One round-robin pass over this client's partition; a worker
+        with nothing buffered gets a 2 ms wait.  None when none served."""
+        mine = self._my_workers()
+        for i in range(len(mine)):
+            w = mine[(self._rr + i) % len(mine)]
+            batch = w.get_batch(timeout=0.0) if w.buffered else None
+            if batch is None and w.alive:
+                batch = w.get_batch(timeout=0.002)
+            if batch is not None:
+                self._rr = (self._rr + i + 1) % max(len(mine), 1)
+                self.metrics.batches += 1
+                self.metrics.rx_bytes += sum(a.nbytes for a in batch.values())
+                return batch
+        if not mine:
+            time.sleep(0.005)
+        return None
+
     def get_batch(
         self, timeout: float = 10.0
     ) -> Optional[Dict[str, np.ndarray]]:
-        """Round-robin poll over this client's worker partition."""
+        """Round-robin poll over this client's worker partition.
+
+        Data-stall time (Table 7) accrues ONLY when the trainer actually
+        waited: a batch served on the first sweep is a zero-stall call.
+        ``stall_s`` counts from the call's start; the ``client.stall``
+        span opens when the first sweep comes back empty."""
         t0 = time.perf_counter()
         deadline = t0 + timeout
-        stalled = False
         self.metrics.wait_calls += 1
-        while time.perf_counter() < deadline:
-            mine = self._my_workers()
-            if not mine:
-                time.sleep(0.005)
-                stalled = True
-                self._check_failed()
-                self._note_stall()
-                continue
-            for i in range(len(mine)):
-                w = mine[(self._rr + i) % len(mine)]
-                batch = w.get_batch(timeout=0.0) if w.buffered else None
-                if batch is None and w.alive:
-                    batch = w.get_batch(timeout=0.002)
-                if batch is not None:
-                    self._rr = (self._rr + i + 1) % max(len(mine), 1)
-                    self.metrics.batches += 1
-                    self.metrics.rx_bytes += sum(a.nbytes for a in batch.values())
-                    # data-stall time (Table 7) accrues ONLY when the
-                    # trainer actually waited; a batch served on the first
-                    # sweep is a zero-stall call, not stall time
-                    if stalled:
-                        self.metrics.stalls += 1
-                        t_now = time.perf_counter()
-                        self.metrics.stall_s += t_now - t0
-                        if self.tracer.enabled:
-                            self.tracer.record(
-                                "client.stall", t0, t_now,
-                                tenant=self.tenant or "",
-                                client=self.client_id,
-                            )
-                    return batch
-            stalled = True
-            self._check_failed()
-            self._note_stall()
-        t_now = time.perf_counter()
-        self.metrics.stall_s += t_now - t0
+        batch = None
+        if time.perf_counter() < deadline:
+            batch = self._sweep()
+            if batch is not None:
+                return batch
+            with self.tracer.span("client.stall", tenant=self.tenant or "",
+                                  client=self.client_id):
+                while True:
+                    self._check_failed()
+                    self._note_stall()
+                    if time.perf_counter() >= deadline:
+                        break
+                    batch = self._sweep()
+                    if batch is not None:
+                        break
         self.metrics.stalls += 1
-        if self.tracer.enabled:
-            self.tracer.record(
-                "client.stall", t0, t_now,
-                tenant=self.tenant or "", client=self.client_id,
-            )
-        self._check_failed()
-        return None
+        self.metrics.stall_s += time.perf_counter() - t0
+        if batch is None:
+            self._check_failed()
+        return batch

@@ -69,6 +69,16 @@ class WorkerMetrics:
     decode_launches: int = counter()           # decode kernel launches
     decode_fused_launches: int = counter()     # batched decode launches alone
     demoted_streams: int = counter()           # streams demoted to per-stream
+    # extract_s by phase (DecodeStats, plus the wait for the stripe's bytes)
+    fetch_wait_s: float = counter(0.0)         # decode thread awaiting the fetch
+    unpack_s: float = counter(0.0)             # host decompress, parse, packing
+    extract_launch_s: float = counter(0.0)     # host side of decode launches
+    extract_assemble_s: float = counter(0.0)   # ColumnBatch construction
+    transform_launch_s: float = counter(0.0)   # host side of transform launches
+    # CPU seconds of the producer and consumer threads over extract_s,
+    # transform_s and load_s: below busy_s when a thread waits or is
+    # kept off the interpreter lock
+    cpu_s: float = counter(0.0)
     # per-extent I/O sizes of this worker's stripe fetches (Table 6)
     io_sizes: List[int] = counter(factory=list)
 
@@ -80,6 +90,12 @@ class WorkerMetrics:
     @property
     def busy_s(self) -> float:
         return self.extract_s + self.transform_s + self.load_s
+
+    @property
+    def launch_s(self) -> float:
+        """Host seconds of every DPP Pallas launch, decode and transform:
+        copies in, dispatch, the wait, the copy out."""
+        return self.extract_launch_s + self.transform_launch_s
 
     @property
     def ingest_rx_bytes(self) -> int:
@@ -151,6 +167,7 @@ class DPPWorker:
         # transform stage executor (§7.2): "numpy" = per-feature reference,
         # "pallas" = wave-fused kernel launches; engines are byte-identical
         self.engine = make_engine(engine, self.pipeline)
+        self.engine.tracer = tracer
         # extract-stage decode strategy, same contract (see repro.core.decode)
         self.decode_engine = decode_engine
         self.double_buffer = double_buffer
@@ -208,54 +225,62 @@ class DPPWorker:
                     break
                 time.sleep(0.01)
                 continue
-            try:
-                batches, cached = self.process_split(reader, split)
-            except Exception:
-                # Extract/transform raised on this split's bytes.  The
-                # worker is fine — only the data is suspect — so report a
-                # typed data_error with the traceback (distinct from a
-                # lease expiry, which signals a LOST worker) and move on
-                # to the next split instead of dying and forcing a
-                # restart-and-retry livelock.
-                self.metrics.data_errors += 1
-                self.master.complete_split(
-                    self.worker_id, split.split_id,
-                    status=REPORT_DATA_ERROR, error=traceback.format_exc(),
-                )
-                continue
-            if not self.master.claim_delivery(self.worker_id, split.split_id):
-                # this copy outlived its lease and another dispatch of the
-                # split was delivered first: drop it, never deliver twice
-                continue
-            delivered = True
-            for batch in batches:
-                placed = False
-                while not self._stop.is_set():
-                    try:
-                        self.buffer.put(batch, timeout=0.1)
-                        placed = True
-                        break
-                    except queue.Full:
-                        # back-pressured on a full buffer, not lost: the
-                        # heartbeat extends our lease so the Master never
-                        # charges a slow consumer as a dead worker
-                        self.master.heartbeat(self.worker_id)
-                        continue
-                if not placed:
-                    delivered = False   # hard-stopped mid-delivery
-                    break
-            if delivered:
-                rows = split.row_end - split.row_start
-                self.metrics.splits_done += 1
-                self.metrics.rows_done += rows
-                if cached:
-                    self.metrics.rows_from_cache += rows
-                self.master.complete_split(self.worker_id, split.split_id)
-            else:
-                # no ok report: the split is re-dispatched rather than
-                # marked done with dropped batches
-                self.master.abandon_delivery(self.worker_id, split.split_id)
+            with self.tracer.bind(tenant=self.tenant or "", worker=self.worker_id,
+                                  split=split.split_id), \
+                    self.tracer.span("worker.split"):
+                self._serve_split(reader, split)
         self.alive = False
+
+    def _serve_split(self, reader: TableReader, split: Split) -> None:
+        """Process one acquired split and deliver its batches, or report
+        why it was not delivered."""
+        try:
+            batches, cached = self.process_split(reader, split)
+        except Exception:
+            # Extract/transform raised on this split's bytes.  The
+            # worker is fine — only the data is suspect — so report a
+            # typed data_error with the traceback (distinct from a
+            # lease expiry, which signals a LOST worker) and move on
+            # to the next split instead of dying and forcing a
+            # restart-and-retry livelock.
+            self.metrics.data_errors += 1
+            self.master.complete_split(
+                self.worker_id, split.split_id,
+                status=REPORT_DATA_ERROR, error=traceback.format_exc(),
+            )
+            return
+        if not self.master.claim_delivery(self.worker_id, split.split_id):
+            # this copy outlived its lease and another dispatch of the
+            # split was delivered first: drop it, never deliver twice
+            return
+        delivered = True
+        for batch in batches:
+            placed = False
+            while not self._stop.is_set():
+                try:
+                    self.buffer.put(batch, timeout=0.1)
+                    placed = True
+                    break
+                except queue.Full:
+                    # back-pressured on a full buffer, not lost: the
+                    # heartbeat extends our lease so the Master never
+                    # charges a slow consumer as a dead worker
+                    self.master.heartbeat(self.worker_id)
+                    continue
+            if not placed:
+                delivered = False   # hard-stopped mid-delivery
+                break
+        if delivered:
+            rows = split.row_end - split.row_start
+            self.metrics.splits_done += 1
+            self.metrics.rows_done += rows
+            if cached:
+                self.metrics.rows_from_cache += rows
+            self.master.complete_split(self.worker_id, split.split_id)
+        else:
+            # no ok report: the split is re-dispatched rather than
+            # marked done with dropped batches
+            self.master.abandon_delivery(self.worker_id, split.split_id)
 
     # -- ETL -------------------------------------------------------------------
 
@@ -295,17 +320,24 @@ class DPPWorker:
                     continue
             return False
 
+        labels = self.tracer.bound()   # this split's labels, for the producer
+
         def _produce() -> None:
+            # each item carries the stripe's extract seconds and this
+            # thread's CPU seconds over the same interval
             try:
-                t0 = time.perf_counter()
-                for sr in reader.iter_stripes(meta, split.row_start, split.row_end):
-                    t1 = time.perf_counter()
-                    if not _put((sr, t1 - t0)):
-                        return
-                    t0 = time.perf_counter()
-                _put((_EOS, 0.0))
+                with self.tracer.bind(**labels):
+                    t0, c0 = time.perf_counter(), time.thread_time()
+                    for sr in reader.iter_stripes(
+                        meta, split.row_start, split.row_end
+                    ):
+                        t1, c1 = time.perf_counter(), time.thread_time()
+                        if not _put((sr, t1 - t0, c1 - c0)):
+                            return
+                        t0, c0 = time.perf_counter(), time.thread_time()
+                _put((_EOS, 0.0, 0.0))
             except BaseException as e:  # surface extraction failures
-                _put((e, 0.0))
+                _put((e, 0.0, 0.0))
 
         producer = threading.Thread(target=_produce, daemon=True)
         producer.start()
@@ -356,7 +388,7 @@ class DPPWorker:
 
         try:
             while True:
-                item, extract_dt = prefetch.get()
+                item, extract_dt, extract_cpu = prefetch.get()
                 if item is _EOS:
                     break
                 if isinstance(item, BaseException):
@@ -365,6 +397,7 @@ class DPPWorker:
                 # long splits must not look like lost workers mid-ETL
                 self.master.heartbeat(self.worker_id)
                 m.extract_s += extract_dt
+                m.fetch_wait_s += sr.fetch_wait_s
                 m.storage_rx_bytes += sr.bytes_from_storage
                 m.cache_rx_bytes += sr.bytes_from_cache
                 m.stripes_read += 1
@@ -372,18 +405,13 @@ class DPPWorker:
                 m.io_sizes.extend(sr.io_sizes)
                 m.extract_out_bytes += sr.batch.nbytes()
 
-                t2 = time.perf_counter()
+                t2, c2 = time.perf_counter(), time.thread_time()
                 env = self.engine.run(sr.batch)
                 t3 = time.perf_counter()
                 m.transform_s += t3 - t2
                 # engine counters are cumulative per exclusive engine, so a
                 # straight mirror keeps the worker metric cumulative too
                 es = self.engine.stats
-                if self.tracer.enabled:
-                    # before the mirror below, m still holds the previous
-                    # cumulative per-path seconds — the difference is this
-                    # stripe's fused/fallback attribution
-                    self._trace_transform(t2, t3, es, m, split.split_id)
                 m.fused_features = es.fused_features
                 m.fallback_features = es.fallback_features
                 m.kernel_launches = es.kernel_launches
@@ -391,6 +419,7 @@ class DPPWorker:
                 m.demoted_features = es.demoted_features
                 m.transform_fused_s = es.fused_s
                 m.transform_fallback_s = es.fallback_s
+                m.transform_launch_s = es.launch_s
 
                 # per-SPLIT label uniformity, checked at stripe arrival:
                 # the _concat_labels guard below only sees one drain window
@@ -407,17 +436,13 @@ class DPPWorker:
                         "the split started "
                         f"{'labeled' if split_labeled else 'unlabeled'}"
                     )
-                pending.append((env, sr.batch.labels, sr.batch.num_rows))
-                pending_rows += sr.batch.num_rows
-                _drain(final=False)
+                with self.tracer.span("load.materialize"):
+                    pending.append((env, sr.batch.labels, sr.batch.num_rows))
+                    pending_rows += sr.batch.num_rows
+                    _drain(final=False)
                 t_load = time.perf_counter()
                 m.load_s += t_load - t3
-                if self.tracer.enabled:
-                    self.tracer.record(
-                        "load.materialize", t3, t_load,
-                        tenant=self.tenant or "", worker=self.worker_id,
-                        split=split.split_id,
-                    )
+                m.cpu_s += extract_cpu + time.thread_time() - c2
         except BaseException:
             abort.set()   # unblock the producer; it exits without a consumer
             raise
@@ -429,44 +454,23 @@ class DPPWorker:
         ds = reader.decode.stats
         m.extract_fused_s = ds.fused_s
         m.extract_fallback_s = ds.fallback_s
+        m.unpack_s = ds.unpack_s
+        m.extract_launch_s = ds.launch_s
+        m.extract_assemble_s = ds.assemble_s
         m.decode_launches = ds.kernel_launches
         m.decode_fused_launches = ds.fused_launches
         m.demoted_streams = ds.demoted_streams
-        t4 = time.perf_counter()
-        _drain(final=True)
-        t_load = time.perf_counter()
-        m.load_s += t_load - t4
-        if self.tracer.enabled:
-            self.tracer.record(
-                "load.materialize", t4, t_load,
-                tenant=self.tenant or "", worker=self.worker_id,
-                split=split.split_id,
-            )
+        t4, c4 = time.perf_counter(), time.thread_time()
+        with self.tracer.span("load.materialize"):
+            _drain(final=True)
+        m.load_s += time.perf_counter() - t4
+        m.cpu_s += time.thread_time() - c4
 
         if self.tensor_cache is not None:
             self.tensor_cache.put(key, out, cpu_s=time.perf_counter() - t_split0)
 
         m.tx_bytes += sum(sum(a.nbytes for a in b.values()) for b in out)
         return out, False
-
-    def _trace_transform(self, t0: float, t1: float, es, m: WorkerMetrics,
-                         split_id: int) -> None:
-        """Record this stripe's transform interval, partitioned into
-        fused/fallback spans by the engine's per-path second deltas
-        (``m`` must still hold the pre-mirror cumulative values)."""
-        d_fused = es.fused_s - m.transform_fused_s
-        d_fallback = es.fallback_s - m.transform_fallback_s
-        labels = dict(tenant=self.tenant or "", worker=self.worker_id,
-                      split=split_id)
-        total = d_fused + d_fallback
-        if total <= 0.0:
-            self.tracer.record("transform.fallback", t0, t1, **labels)
-            return
-        cut = t0 + (t1 - t0) * (d_fused / total)
-        if d_fused > 0.0:
-            self.tracer.record("transform.fused", t0, cut, **labels)
-        if d_fallback > 0.0:
-            self.tracer.record("transform.fallback", cut, t1, **labels)
 
     # -- serving to clients ------------------------------------------------------
 

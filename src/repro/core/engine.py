@@ -28,12 +28,14 @@ engine-agnostic.
 
 ``EngineStats`` feeds ``WorkerMetrics`` (fused vs fallback feature counts,
 kernel launches, per-path transform seconds) so Table-9-style breakdowns
-can compare engines.
+can compare engines.  Each fused wave is a ``transform.fused`` span, each
+pass of consecutive numpy ops a ``transform.fallback`` span, and each wave
+launch a ``kernel.fused_transform`` span inside its wave.
 """
 from __future__ import annotations
 
 import dataclasses
-import time
+import itertools
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -45,7 +47,7 @@ from repro.core.transforms import (
     TransformPipeline,
     TransformSpec,
 )
-from repro.obs import counter
+from repro.obs import NULL_TRACER, counter, phase
 
 # Op codes mirror repro.kernels.fused_transform (kept import-light: jax is
 # only pulled in when a PallasEngine actually launches a wave).
@@ -86,6 +88,7 @@ class EngineStats:
     fused_launches: int = counter()      # fused wave launches alone
     fused_s: float = counter(0.0)        # transform_s attribution: fused path
     fallback_s: float = counter(0.0)     # transform_s attribution: numpy path
+    launch_s: float = counter(0.0)       # of fused_s: launches, copy in to out
 
 
 # ---------------------------------------------------------------------------
@@ -291,6 +294,7 @@ class TransformEngine:
     """Executes a session's transform DAG over a ColumnBatch."""
 
     name = "base"
+    tracer = NULL_TRACER        # the owning worker's, for the phase spans
 
     def __init__(self, pipeline: TransformPipeline):
         self.pipeline = pipeline
@@ -313,13 +317,16 @@ class TransformEngine:
             env[f"f{fid}"] = col
         return env
 
-    def _apply_fallback(self, spec: TransformSpec, env: Dict[str, Column]) -> None:
-        t0 = time.perf_counter()
-        fn = _OPS[spec.op]
-        env[spec.output] = fn(*[env[i] for i in spec.inputs], **spec.kwargs)
-        self.stats.fallback_s += time.perf_counter() - t0
-        self.stats.fallback_features += 1
-        self.stats.kernel_launches += 1
+    def _fallback_pass(self, specs: Sequence[TransformSpec],
+                       env: Dict[str, Column]) -> None:
+        """Run consecutive per-feature numpy ops as one traced pass."""
+        with phase(self.tracer, "transform.fallback", self.stats, "fallback_s"):
+            for spec in specs:
+                fn = _OPS[spec.op]
+                env[spec.output] = fn(*[env[i] for i in spec.inputs],
+                                      **spec.kwargs)
+                self.stats.fallback_features += 1
+                self.stats.kernel_launches += 1
 
 
 class NumpyEngine(TransformEngine):
@@ -331,8 +338,7 @@ class NumpyEngine(TransformEngine):
 
     def run(self, batch: ColumnBatch) -> Dict[str, Column]:
         env = self._seed_env(batch)
-        for spec in self.pipeline.specs:
-            self._apply_fallback(spec, env)
+        self._fallback_pass(self.pipeline.specs, env)
         return env
 
 
@@ -367,6 +373,11 @@ class PallasEngine(TransformEngine):
     ):
         super().__init__(pipeline)
         self.plan = compile_pipeline(pipeline.specs)
+        # the plan as passes: runs of consecutive fallback steps, and waves
+        self._passes = [
+            (fallback, tuple(steps)) for fallback, steps in itertools.groupby(
+                self.plan.steps, key=lambda st: isinstance(st, FallbackStep))
+        ]
         self.block_rows = block_rows
         self.block_cols = block_cols
         self.row_quantum = max(1, row_quantum)
@@ -374,11 +385,12 @@ class PallasEngine(TransformEngine):
 
     def run(self, batch: ColumnBatch) -> Dict[str, Column]:
         env = self._seed_env(batch)
-        for step in self.plan.steps:
-            if isinstance(step, FallbackStep):
-                self._apply_fallback(step.spec, env)
+        for fallback, steps in self._passes:
+            if fallback:
+                self._fallback_pass([st.spec for st in steps], env)
             else:
-                self._run_wave(step, env)
+                for wave in steps:
+                    self._run_wave(wave, env)
         return env
 
     # -- wave execution -----------------------------------------------------
@@ -408,7 +420,15 @@ class PallasEngine(TransformEngine):
         return v32.view(np.int32)
 
     def _run_wave(self, wave: FusedWave, env: Dict[str, Column]) -> None:
-        t0 = time.perf_counter()
+        with phase(self.tracer, "transform.fused", self.stats, "fused_s"):
+            demoted = self._run_fused(wave, env)
+        if demoted:
+            self.stats.demoted_features += len(demoted)
+            self._fallback_pass([fop.spec for fop in demoted], env)
+
+    def _run_fused(self, wave: FusedWave, env: Dict[str, Column]) -> List[FusedOp]:
+        """Pack, launch and unpack the wave's fusable ops; returns the ops
+        demoted to the numpy fallback."""
         entries: List[Tuple[FusedOp, Column, np.ndarray]] = []
         demoted: List[FusedOp] = []
         for fop in wave.ops:
@@ -418,53 +438,52 @@ class PallasEngine(TransformEngine):
                 demoted.append(fop)
             else:
                 entries.append((fop, col, packed))
+        if not entries:
+            return demoted
 
-        if entries:
-            rows = max(len(p) for _, _, p in entries)
-            feats = len(entries)
-            if rows == 0:
-                out32 = np.zeros((feats, 0), np.int32)
-            else:
-                # features-major packing: one contiguous row per feature
-                # (fast fills; int64 ids wrap to their low 32 bits on
-                # assignment, matching the kernel's lane truncation)
-                q = self.row_quantum
-                rows_pad = -(-rows // q) * q
-                mat = np.zeros((feats, rows_pad), np.int32)
-                codes = np.zeros(feats, np.int32)
-                p0 = np.zeros(feats, np.int32)
-                p1 = np.zeros(feats, np.int32)
-                nb = max(
-                    [f.borders.size for f, _, _ in entries if f.borders is not None],
-                    default=1,
-                )
-                borders = np.full((feats, nb), np.inf, np.float32)
-                for j, (fop, _, packed) in enumerate(entries):
-                    mat[j, : len(packed)] = packed
-                    codes[j] = fop.code
-                    p0[j] = fop.p0
-                    p1[j] = fop.p1
-                    if fop.borders is not None:
-                        borders[j, : fop.borders.size] = fop.borders
-                out32 = self._launch(mat, codes, p0, p1, borders)
-                self.stats.fused_launches += 1
-            self.stats.kernel_launches += 1
-            self.stats.fused_features += feats
-            # vectorized unpack: at most one widening cast for the whole
-            # wave; per-feature outputs are contiguous row views
-            out64 = (
-                out32.astype(np.int64)
-                if any(f.kind != "dense" for f, _, _ in entries) else None
+        rows = max(len(p) for _, _, p in entries)
+        feats = len(entries)
+        if rows == 0:
+            out32 = np.zeros((feats, 0), np.int32)
+        else:
+            # features-major packing: one contiguous row per feature
+            # (fast fills; int64 ids wrap to their low 32 bits on
+            # assignment, matching the kernel's lane truncation)
+            q = self.row_quantum
+            rows_pad = -(-rows // q) * q
+            mat = np.zeros((feats, rows_pad), np.int32)
+            codes = np.zeros(feats, np.int32)
+            p0 = np.zeros(feats, np.int32)
+            p1 = np.zeros(feats, np.int32)
+            nb = max(
+                [f.borders.size for f, _, _ in entries if f.borders is not None],
+                default=1,
             )
-            for j, (fop, col, packed) in enumerate(entries):
-                env[fop.spec.output] = self._unpack(
-                    fop, col, out32, out64, j, len(packed)
-                )
-            self.stats.fused_s += time.perf_counter() - t0
-
-        for fop in demoted:
-            self.stats.demoted_features += 1
-            self._apply_fallback(fop.spec, env)
+            borders = np.full((feats, nb), np.inf, np.float32)
+            for j, (fop, _, packed) in enumerate(entries):
+                mat[j, : len(packed)] = packed
+                codes[j] = fop.code
+                p0[j] = fop.p0
+                p1[j] = fop.p1
+                if fop.borders is not None:
+                    borders[j, : fop.borders.size] = fop.borders
+            with phase(self.tracer, "kernel.fused_transform", self.stats,
+                       "launch_s"):
+                out32 = self._launch(mat, codes, p0, p1, borders)
+            self.stats.fused_launches += 1
+        self.stats.kernel_launches += 1
+        self.stats.fused_features += feats
+        # vectorized unpack: at most one widening cast for the whole
+        # wave; per-feature outputs are contiguous row views
+        out64 = (
+            out32.astype(np.int64)
+            if any(f.kind != "dense" for f, _, _ in entries) else None
+        )
+        for j, (fop, col, packed) in enumerate(entries):
+            env[fop.spec.output] = self._unpack(
+                fop, col, out32, out64, j, len(packed)
+            )
+        return demoted
 
     def _launch(self, mat, codes, p0, p1, borders) -> np.ndarray:
         """Run one wave over the (features, rows) packed tile; returns the

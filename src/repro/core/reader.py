@@ -21,6 +21,7 @@ producer/consumer pipelines; ``read_rows`` materializes an exact row range.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -81,6 +82,9 @@ class StripeRead:
     # previously only read_rows reported these, so streaming consumers
     # lost the size histogram entirely)
     io_sizes: List[int] = dataclasses.field(default_factory=list)
+    # seconds the decoding thread waited for this stripe's bytes: the
+    # join on the prefetch thread, or the fetch itself when inline
+    fetch_wait_s: float = 0.0
 
 
 def _trim_stripe(
@@ -209,6 +213,7 @@ class TableReader:
         # repro.core.decode); engines are byte-compatible, so this never
         # changes the batches, only how they are produced
         self.decode = make_decode_engine(decode_engine)
+        self.decode.tracer = tracer
         # overlap stripe N+1's extent fetch with stripe N's decode in
         # iter_stripes (the producer half of the DPP worker)
         self.double_buffer = double_buffer
@@ -333,10 +338,13 @@ class TableReader:
             import threading
 
             slot: Dict[str, object] = {}
+            labels = self.tracer.bound()
 
             def run():
                 try:
-                    slot["res"] = self._fetch_streams(meta, plans[k][1])
+                    with self.tracer.bind(**labels), \
+                            self.tracer.span("extract.fetch", stripe=plans[k][0]):
+                        slot["res"] = self._fetch_streams(meta, plans[k][1])
                 except BaseException as exc:
                     slot["err"] = exc
 
@@ -348,18 +356,24 @@ class TableReader:
 
         pending = _start_fetch(0) if self.double_buffer and plans else None
         for k, (si, plan) in enumerate(plans):
+            t_wait = time.perf_counter()
+            with self.tracer.span("extract.fetch_wait", stripe=si):
+                if pending is not None:
+                    slot, th = pending
+                    th.join()
+                else:
+                    with self.tracer.span("extract.fetch", stripe=si):
+                        fetched = self._fetch_streams(meta, plan)
+            fetch_wait_s = time.perf_counter() - t_wait
             if pending is not None:
-                slot, th = pending
-                th.join()
                 # start stripe k+1's fetch before decoding stripe k
                 pending = (
                     _start_fetch(k + 1) if k + 1 < len(plans) else None
                 )
                 if "err" in slot:
                     raise slot["err"]
-                per_stripe, feature_bytes, io = slot["res"]
-            else:
-                per_stripe, feature_bytes, io = self._fetch_streams(meta, plan)
+                fetched = slot["res"]
+            per_stripe, feature_bytes, io = fetched
             stripe = footer.stripes[si]
             with self.tracer.span(
                 "extract.decode", tenant=self.tenant or "",
@@ -383,6 +397,7 @@ class TableReader:
                 bytes_from_cache=io.cache_bytes,
                 bytes_from_storage=io.storage_bytes,
                 io_sizes=[l for _, l in plan.extents],
+                fetch_wait_s=fetch_wait_s,
             )
 
     def read_partition(

@@ -23,6 +23,9 @@ most three launches:
     the concatenated payload buffer: ``out = src[idx] >> shift | src[idx
     + 1] << (32 - shift)``, one launch for every region of every stream.
 
+Each ``pallas_call`` is named after its kernel, so the device trace shows
+it as the custom call ``%<name>.<n>`` whatever jitted wrapper calls it.
+
 ``repro.core.decode.PallasDecodeEngine`` packs the operands and owns the
 demotion rules; the jnp oracles live in ``repro.kernels.ref`` and the
 dispatch wrappers in ``repro.kernels.ops`` (same ``use_pallas`` contract
@@ -65,6 +68,7 @@ def xor_decrypt(
         ),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
         interpret=interpret,
+        name="xor_decrypt",
     )(words)
 
 
@@ -146,6 +150,7 @@ def dense_unpack(
         ),
         out_shape=jax.ShapeDtypeStruct((feats, rows_pad), jnp.int32),
         interpret=interpret,
+        name="dense_unpack",
     )(bitmap_words, values)
     return out[:, : w * 32]
 
@@ -219,4 +224,5 @@ def ragged_gather(
         ),
         out_shape=jax.ShapeDtypeStruct((m, lanes), jnp.int32),
         interpret=interpret,
+        name="ragged_gather",
     )(win, src, idx, shift)

@@ -117,5 +117,6 @@ def fused_transform(
         ),
         out_shape=jax.ShapeDtypeStruct((rows, feats), jnp.int32),
         interpret=interpret,
+        name="fused_transform",   # the custom call's name in the device trace
     )(ids, row(op_codes), row(param0), row(param1),
       borders.astype(jnp.float32).T)           # (nb, features): rows per border

@@ -3,8 +3,10 @@
 Three stdlib-only pieces, threaded through every DSI stage:
 
   * :mod:`repro.obs.trace` — thread-safe span tracing with clock
-    injection and a Chrome-trace/Perfetto exporter.  Disabled by default
-    (``NULL_TRACER``), zero-cost when off.
+    injection and a Chrome-trace/Perfetto exporter; enabled spans also
+    reach a ``jax.profiler`` trace.  Disabled by default
+    (``NULL_TRACER``), zero-cost when off; ``phase`` times a span into
+    counters either way.
   * :mod:`repro.obs.meta` — per-field counter/gauge metadata for the
     metric dataclasses; one source of truth shared by ``merge`` methods,
     the registry, and the REPRO-M002 monotonicity rule.
@@ -19,10 +21,10 @@ CI (see docs/observability.md).
 """
 from repro.obs.meta import counter, gauge, merge_metrics, metric_fields
 from repro.obs.registry import MetricsRegistry, Snapshot
-from repro.obs.trace import NULL_TRACER, NullTracer, Tracer
+from repro.obs.trace import NULL_TRACER, NullTracer, Tracer, phase
 
 __all__ = [
     "counter", "gauge", "merge_metrics", "metric_fields",
     "MetricsRegistry", "Snapshot",
-    "Tracer", "NullTracer", "NULL_TRACER",
+    "Tracer", "NullTracer", "NULL_TRACER", "phase",
 ]

@@ -6,7 +6,7 @@ carrying a registry-snapshot ``metrics`` payload) and prints, per tenant
 plus an ``ALL`` aggregate:
 
   * the share of wall-clock the trainer spent blocked (``client.stall``)
-    attributed across storage reads, cache fills, extract+transform and
+    attributed across storage reads, cache fills, extract, transform and
     load/materialize — the paper's Table 7 breakdown — plus the directly
     measured tiered-embedding fetch share (``embed.fetch``, ISSUE 9) and
     the remainder as compute.  Shares sum to 100 by construction.
@@ -27,22 +27,29 @@ import json
 import sys
 from typing import Any, Dict, List, Optional
 
-# span name -> stall-attribution bucket (Table 7 rows)
+# span name -> stall-attribution bucket (Table 7 rows).  A span inside
+# another bucketed span on its thread (the phases of ``extract.decode``)
+# is already counted by its parent.  ``extract.fetch`` and
+# ``extract.fetch_wait`` are left out: the work they wait on is the
+# ``storage.read`` / ``cache.fill`` inside the fetch.
 _BUCKETS = {
     "storage.read": "storage",
     "cache.fill": "cache_fill",
-    "extract.decode": "transform",
+    "extract.decode": "extract",
+    "extract.unpack": "extract",
+    "extract.host": "extract",
+    "extract.assemble": "extract",
     "transform.fused": "transform",
     "transform.fallback": "transform",
     "load.materialize": "load",
 }
-_WEIGHTS = ("storage", "cache_fill", "transform", "load")
+_WEIGHTS = ("storage", "cache_fill", "extract", "transform", "load")
 # directly-measured (non-blocked) trainer-side categories: unlike the
 # _BUCKETS weights these are not a split of client.stall — they are their
 # own slice of the wall clock (tiered embedding lookups, ISSUE 9)
 _EMBED_SPAN = "embed.fetch"
 _SHARE_KEYS = (
-    "storage_pct", "cache_fill_pct", "transform_pct", "load_pct",
+    "storage_pct", "cache_fill_pct", "extract_pct", "transform_pct", "load_pct",
     "embed_fetch_pct", "compute_pct", "unattributed_pct",
 )
 # registry-snapshot names the byte/efficiency columns read
@@ -79,7 +86,7 @@ def _accumulate(evs: List[Dict[str, Any]]) -> Dict[str, float]:
         row[f"w_{b}_us"] = 0.0
     for e in evs:
         b = _BUCKETS.get(e["name"])
-        if b is not None:
+        if b is not None and (e.get("args") or {}).get("parent") not in _BUCKETS:
             row[f"w_{b}_us"] += e["dur"]
     return row
 
@@ -207,14 +214,15 @@ def check(doc: Dict[str, Any]) -> List[str]:
 def _fmt_table(rows: Dict[str, Dict[str, float]]) -> str:
     head = (
         f"{'tenant':<12} {'wall_s':>8} {'storage%':>9} {'cachefill%':>10} "
-        f"{'transform%':>10} {'load%':>7} {'embed%':>7} {'compute%':>9} "
-        f"{'unattr%':>8}"
+        f"{'extract%':>9} {'transform%':>10} {'load%':>7} {'embed%':>7} "
+        f"{'compute%':>9} {'unattr%':>8}"
     )
     lines = [head, "-" * len(head)]
     for tenant, r in rows.items():
         lines.append(
             f"{tenant or '(none)':<12} {r['wall_us'] / 1e6:>8.2f} "
             f"{r['storage_pct']:>9.2f} {r['cache_fill_pct']:>10.2f} "
+            f"{r['extract_pct']:>9.2f} "
             f"{r['transform_pct']:>10.2f} {r['load_pct']:>7.2f} "
             f"{r['embed_fetch_pct']:>7.2f} "
             f"{r['compute_pct']:>9.2f} {r['unattributed_pct']:>8.2f}"

@@ -39,6 +39,9 @@ from repro.obs import NULL_TRACER, MetricsRegistry, counter, gauge
 from repro.optim import OptimizerConfig, adamw_init, adamw_update, wsd_schedule
 
 
+_END = object()     # the batch iterator is exhausted
+
+
 @dataclasses.dataclass
 class TrainerConfig:
     checkpoint_dir: Optional[str] = None
@@ -214,54 +217,55 @@ class Trainer:
         params, opt, step = state["params"], state["opt"], state["step"]
 
         it = iter(batches)
+        tr = self.tracer
+        stall_tr = tr if self.cfg.trace_stall else NULL_TRACER
         while step < self.cfg.max_steps:
+            labels = self._span_labels(step + 1)
             t0 = time.perf_counter()
-            try:
-                batch = next(it)
-            except StopIteration:
+            with stall_tr.span("client.stall", **labels):
+                # batch-fetch wait: trainer-side stall (Table 7)
+                batch = next(it, _END)
+            if batch is _END:
                 break
             if batch is None:
                 continue
             t1 = time.perf_counter()
             if self._sparse:
-                ids = np.asarray(batch["sparse_ids"])
-                smask = np.asarray(batch["sparse_mask"], np.float32)
-                pooled = self.store.pooled(
-                    ids, smask, use_kernel=self.cfg.kernel_bags
-                )
+                with tr.span("embed.fetch", **labels):
+                    # tiered-embedding lookup: the embed-fetch share
+                    ids = np.asarray(batch["sparse_ids"])
+                    smask = np.asarray(batch["sparse_mask"], np.float32)
+                    pooled = self.store.pooled(
+                        ids, smask, use_kernel=self.cfg.kernel_bags
+                    )
                 te = time.perf_counter()
-                jb = {
-                    "dense": jnp.asarray(batch["dense"]),
-                    "label": jnp.asarray(batch["label"]),
-                }
-                params, opt, loss, gnorm, dpooled, lr = self._train_step(
-                    params, opt, jnp.asarray(pooled), jb
-                )
-                self.store.apply_sparse_update(
-                    np.asarray(dpooled), ids, smask, lr=float(lr)
-                )
+                with tr.span("train.step", **labels):
+                    with tr.span("train.put", **labels):
+                        jb = {
+                            "dense": jnp.asarray(batch["dense"]),
+                            "label": jnp.asarray(batch["label"]),
+                        }
+                        jpooled = jnp.asarray(pooled)
+                    with tr.span("train.dispatch", **labels):
+                        params, opt, loss, gnorm, dpooled, lr = \
+                            self._train_step(params, opt, jpooled, jb)
+                    self.store.apply_sparse_update(
+                        np.asarray(dpooled), ids, smask, lr=float(lr)
+                    )
             else:
                 te = t1
-                jb = {k: jnp.asarray(v) for k, v in batch.items()}
-                params, opt, loss, gnorm = self._train_step(params, opt, jb)
+                with tr.span("train.step", **labels):
+                    with tr.span("train.put", **labels):
+                        jb = {k: jnp.asarray(v) for k, v in batch.items()}
+                    with tr.span("train.dispatch", **labels):
+                        params, opt, loss, gnorm = self._train_step(params, opt, jb)
             step += 1
             t2 = time.perf_counter()
-            if self.tracer.enabled:
-                if self.cfg.trace_stall and t1 > t0:
-                    # batch-fetch wait: trainer-side stall (Table 7)
-                    self.tracer.record(
-                        "client.stall", t0, t1, **self._span_labels(step)
-                    )
-                if te > t1:
-                    # tiered-embedding lookup: the embed-fetch share
-                    self.tracer.record(
-                        "embed.fetch", t1, te, **self._span_labels(step)
-                    )
-                self.tracer.record(
-                    "train.step", te, t2, **self._span_labels(step)
-                )
+            with tr.span("train.wait", **labels):
+                # the host waits here for the device step to finish
+                loss_v, gnorm_v = float(loss), float(gnorm)
             m = StepMetrics(
-                step=step, loss=float(loss), grad_norm=float(gnorm),
+                step=step, loss=loss_v, grad_norm=gnorm_v,
                 step_time_s=t2 - te, stall_s=t1 - t0,
                 embed_fetch_s=te - t1,
                 hot_rate=self.store.stats.hot_rate if self._sparse else 0.0,

@@ -545,6 +545,21 @@ def test_s001_with_span_and_atomic_apis_ok(tmp_path):
     assert _findings(repo, "REPRO-S001") == []
 
 
+def test_s001_phase_outside_with(tmp_path):
+    repo = _repo(tmp_path, {"src/repro/core/foo.py": """\
+        from repro.obs import phase
+
+        class Thing:
+            def work(self, stats):
+                with phase(self.tracer, "extract.host", stats, "host_s"):
+                    pass
+                p = phase(self.tracer, "extract.unpack", stats, "unpack_s")
+                p.__enter__()
+    """})
+    f = _findings(repo, "REPRO-S001")
+    assert len(f) == 1 and f[0].symbol == "Thing.work"
+
+
 def test_s001_scope_is_core_only(tmp_path):
     repo = _repo(tmp_path, {"src/repro/train/foo.py": """\
         def work(tracer):

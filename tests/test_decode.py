@@ -437,3 +437,73 @@ def test_session_pallas_decode_bit_identical_and_metered():
     # the whole epoch costs a handful of launches per stripe, not O(features)
     n_stripes = m.stripes_read
     assert m.decode_launches <= 4 * n_stripes
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_session_phase_counters_and_spans(traced):
+    """Each extract phase counter runs whether tracing is on or off and
+    they sum to no more than ``extract_s``; traced, every span of a split
+    carries its ``split`` label on every thread."""
+    from repro.obs import NULL_TRACER, Tracer
+
+    t = _table(name=f"phases{int(traced)}")
+    tracer = Tracer() if traced else NULL_TRACER
+    sess = DPPSession(_session_spec(t), t, n_workers=2, decode_engine="pallas",
+                      engine="pallas", double_buffer=True, tracer=tracer)
+    assert sess.run_to_completion(timeout_s=60)
+    m = sess.worker_metrics()
+    for k in ("fetch_wait_s", "unpack_s", "extract_launch_s",
+              "extract_assemble_s", "extract_fallback_s",
+              "transform_launch_s", "cpu_s"):
+        assert getattr(m, k) > 0.0, k
+    assert m.launch_s == m.extract_launch_s + m.transform_launch_s
+    phases = (m.fetch_wait_s + m.unpack_s + m.extract_launch_s
+              + m.extract_fallback_s + m.extract_assemble_s)
+    assert phases <= m.extract_s
+    assert m.transform_launch_s <= m.transform_fused_s
+    if not traced:
+        return
+    spans = tracer.spans()
+    names = {s.name for s in spans}
+    assert {"worker.split", "extract.fetch_wait", "extract.fetch",
+            "extract.decode", "extract.unpack", "extract.host",
+            "extract.assemble", "kernel.xor_decrypt", "kernel.dense_unpack",
+            "kernel.ragged_gather", "kernel.fused_transform",
+            "transform.fused", "transform.fallback",
+            "load.materialize"} <= names
+    assert not any(n.startswith("trainer.") for n in names)
+    per_split = [s for s in spans if s.name.split(".")[0] in
+                 ("worker", "extract", "kernel", "transform", "load", "storage")]
+    assert all("split" in s.labels for s in per_split)
+    assert len({s.tid for s in per_split}) >= 4   # worker, producer, fetch...
+    splits = {s.labels["split"] for s in spans if s.name == "worker.split"}
+    assert splits == {s.labels["split"] for s in per_split}
+    assert tracer.open_spans() == 0 and tracer.dropped_spans() == 0
+
+
+def test_decode_stats_phases_cover_the_decode():
+    """The phase counters split the decode without overlap: the batched
+    path's fused seconds are its unpack, launch and assemble phases."""
+    rows = 128
+    rng = np.random.default_rng(7)
+    dense = {f: rng.standard_normal(rows).astype(np.float32) for f in range(4)}
+    off = np.arange(rows + 1, dtype=np.int64)
+    sparse = {f: SparseColumn(offsets=off, values=rng.integers(0, 99, rows),
+                              scores=None) for f in range(4, 6)}
+    batch = ColumnBatch(num_rows=rows, dense=dense, sparse=sparse,
+                        labels=rng.random(rows).astype(np.float32))
+    f = dwrf.write_dwrf(batch, dwrf.DwrfWriterOptions(
+        flattened=True, stripe_rows=rows, codec="raw"))
+    stripe = f.footer.stripes[0]
+    fetch = _stripe_fetch(f, stripe)
+    fids = list(range(6))
+    ep = PallasDecodeEngine()
+    ep.decode_stripe(stripe, fetch, fids)
+    st = ep.stats
+    assert st.unpack_s > 0 and st.launch_s > 0 and st.assemble_s > 0
+    assert st.fallback_s > 0                       # labels decode on the host
+    assert st.fused_s == pytest.approx(st.unpack_s + st.launch_s + st.assemble_s)
+    en = NumpyDecodeEngine()
+    en.decode_stripe(stripe, fetch, fids)
+    assert en.stats.fallback_s > 0 and en.stats.assemble_s > 0
+    assert en.stats.unpack_s == en.stats.launch_s == en.stats.fused_s == 0

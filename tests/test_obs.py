@@ -15,7 +15,7 @@ from repro.core.dpp.autoscale import (
 )
 from repro.obs import (
     NULL_TRACER, MetricsRegistry, NullTracer, Snapshot, Tracer,
-    counter, gauge, merge_metrics,
+    counter, gauge, merge_metrics, phase,
 )
 from repro.obs.meta import flatten_metrics
 from repro.obs.report import build_report, check
@@ -107,7 +107,9 @@ def test_span_durations_from_injected_clock():
     [s] = tr.spans()
     assert s.name == "storage.read"
     assert s.duration == pytest.approx(0.25)
+    cpu_s = s.labels.pop("cpu_s")          # the thread's CPU over the span
     assert s.labels == {"tenant": "a", "bytes": 128}
+    assert 0.0 <= cpu_s < 1.0
     assert s.parent is None
 
 
@@ -205,6 +207,105 @@ def test_null_tracer_is_allocation_free_singletons():
     assert NULL_TRACER.record("x", 0.0, 1.0) is None
     assert NULL_TRACER.spans() == []
     assert NULL_TRACER.chrome_trace()["traceEvents"] == []
+
+
+def test_null_tracer_allocates_and_reads_no_clock(monkeypatch):
+    """The disabled hot path: no clock read, nothing retained."""
+    import time
+    import tracemalloc
+
+    def no_clock():
+        raise AssertionError("NullTracer read a clock")
+
+    monkeypatch.setattr(time, "perf_counter", no_clock)
+    monkeypatch.setattr(time, "thread_time", no_clock)
+    tr = NULL_TRACER
+    assert tr.bind(split=1) is tr.span("x") and tr.bound() == {}
+    tracemalloc.start()
+    try:
+        before = tracemalloc.get_traced_memory()[0]
+        for _ in range(1000):
+            with tr.bind(split=1), tr.span("worker.split"):
+                with tr.span("extract.unpack"):
+                    pass
+        after = tracemalloc.get_traced_memory()[0]
+    finally:
+        tracemalloc.stop()
+    assert after - before < 256            # nothing kept per span
+
+
+def test_bound_labels_reach_every_span_of_the_thread_and_its_children():
+    tr = Tracer(clock=FakeClock())
+    with tr.bind(split=7, worker="w0"):
+        with tr.span("worker.split"):
+            with tr.span("extract.unpack", stripe=2):
+                pass
+        carried = tr.bound()
+
+        def fetch() -> None:
+            with tr.bind(**carried), tr.span("extract.fetch"):
+                pass
+
+        t = threading.Thread(target=fetch)
+        t.start()
+        t.join(timeout=5)
+        assert not t.is_alive()
+    with tr.span("after"):
+        pass
+    spans = {s.name: s for s in tr.spans()}
+    for name in ("worker.split", "extract.unpack", "extract.fetch"):
+        assert spans[name].labels["split"] == 7, name
+        assert spans[name].labels["worker"] == "w0", name
+    assert spans["extract.unpack"].labels["stripe"] == 2
+    assert spans["extract.fetch"].tid != spans["worker.split"].tid
+    assert "split" not in spans["after"].labels      # the binding ended
+
+
+def test_phase_counts_with_tracing_on_or_off():
+    @dataclasses.dataclass
+    class _Stats:
+        unpack_s: float = counter(0.0)
+        fused_s: float = counter(0.0)
+
+    clock = FakeClock()
+    for tracer in (NULL_TRACER, Tracer(clock=clock)):
+        st = _Stats()
+        with phase(tracer, "extract.unpack", st, "unpack_s", "fused_s"):
+            clock.advance(1.0)
+            sum(range(10_000))
+        assert st.unpack_s > 0.0 and st.fused_s == st.unpack_s
+    [sp] = tracer.spans()
+    assert sp.name == "extract.unpack" and sp.duration == pytest.approx(1.0)
+
+
+def test_enabled_span_reaches_the_profilers_host_trace(tmp_path):
+    """An enabled span is a ``TraceAnnotation`` of the same name and
+    labels on the host line of a ``jax.profiler`` trace (here the CPU's)."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.bind(split=11), tr.span("worker.split"):
+            with tr.span("kernel.xor_decrypt"):
+                jnp.ones(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in ("worker.split", "kernel.xor_decrypt"):
+                        found[e.name] = dict(e.stats)
+    assert set(found) == {"worker.split", "kernel.xor_decrypt"}
+    assert int(found["worker.split"]["split"]) == 11
+    assert {s.name for s in tr.spans()} == set(found)
 
 
 # -- registry -----------------------------------------------------------------
@@ -332,16 +433,17 @@ def test_report_shares_sum_to_100_and_split_proportionally():
     }
     rows = build_report(doc)
     r = rows["a"]
-    total = (r["storage_pct"] + r["cache_fill_pct"] + r["transform_pct"]
-             + r["load_pct"] + r["embed_fetch_pct"] + r["compute_pct"]
-             + r["unattributed_pct"])
+    total = (r["storage_pct"] + r["cache_fill_pct"] + r["extract_pct"]
+             + r["transform_pct"] + r["load_pct"] + r["embed_fetch_pct"]
+             + r["compute_pct"] + r["unattributed_pct"])
     assert total == pytest.approx(100.0, abs=1e-9)
     assert r["embed_fetch_pct"] == 0.0      # no embed.fetch spans recorded
     assert r["compute_pct"] == pytest.approx(60.0)
     # blocked 40% split by span weight: storage 30/60, fill 10/60, ...
     assert r["storage_pct"] == pytest.approx(20.0)
     assert r["cache_fill_pct"] == pytest.approx(40.0 * 10 / 60)
-    assert r["transform_pct"] == pytest.approx(40.0 * 10 / 60)
+    assert r["extract_pct"] == pytest.approx(40.0 * 5 / 60)
+    assert r["transform_pct"] == pytest.approx(40.0 * 5 / 60)
     assert r["load_pct"] == pytest.approx(40.0 * 10 / 60)
     assert r["unattributed_pct"] == 0.0
     assert check(doc) == []
@@ -365,9 +467,9 @@ def test_report_embed_fetch_is_direct_share_not_stall_split():
     assert r["embed_fetch_pct"] == pytest.approx(20.0)
     assert r["storage_pct"] == pytest.approx(40.0)    # full blocked share
     assert r["compute_pct"] == pytest.approx(40.0)
-    total = (r["storage_pct"] + r["cache_fill_pct"] + r["transform_pct"]
-             + r["load_pct"] + r["embed_fetch_pct"] + r["compute_pct"]
-             + r["unattributed_pct"])
+    total = (r["storage_pct"] + r["cache_fill_pct"] + r["extract_pct"]
+             + r["transform_pct"] + r["load_pct"] + r["embed_fetch_pct"]
+             + r["compute_pct"] + r["unattributed_pct"])
     assert total == pytest.approx(100.0, abs=1e-9)
     assert check(doc) == []
 
@@ -460,8 +562,11 @@ def test_smoke_artifact_passes_report_check(tmp_path):
     assert {"tenant_a", "tenant_b", "ALL"} <= set(rows)
     for r in rows.values():
         assert sum(r[k] for k in (
-            "storage_pct", "cache_fill_pct", "transform_pct", "load_pct",
-            "embed_fetch_pct", "compute_pct", "unattributed_pct",
+            "storage_pct", "cache_fill_pct", "extract_pct", "transform_pct",
+            "load_pct", "embed_fetch_pct", "compute_pct", "unattributed_pct",
         )) == pytest.approx(100.0, abs=0.1)
+    # the DPP phases land in their own buckets
+    assert rows["ALL"]["extract_pct"] > 0.0
+    assert rows["ALL"]["transform_pct"] > 0.0
     assert report_main([str(out), "--check"]) == 0
     assert report_main([str(out), "--json"]) == 0

@@ -17,6 +17,7 @@ every test file.
 import dataclasses
 import importlib
 import os
+import re
 
 import pytest
 
@@ -51,10 +52,16 @@ def _spec(sharding, shape, dtype=jnp.int32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile_kernel(fn, sharding, *shapes):
+def _compile_kernel(fn, sharding, *shapes, name=None):
+    """Compile ``fn`` for the described chip.  With ``name``, the kernel's
+    custom call must carry it, as the device trace's readers match it
+    (``%<name>.<n> = ... custom-call(...)``)."""
     args = [_spec(sharding, s, d) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if name is not None:
+        assert re.search(rf"%{name}\.\d+ = [^\n]*custom-call\(", text), name
     return compiled
 
 
@@ -64,7 +71,7 @@ I32, F32 = jnp.int32, jnp.float32
 def test_xor_decrypt_compiles(one_chip):
     from repro.kernels.decode import xor_decrypt
 
-    _compile_kernel(xor_decrypt, one_chip, ((1374, 128), I32))
+    _compile_kernel(xor_decrypt, one_chip, ((1374, 128), I32), name="xor_decrypt")
 
 
 @pytest.mark.parametrize("stripe_rows,values", [
@@ -78,7 +85,8 @@ def test_dense_unpack_compiles(one_chip, stripe_rows, values):
     from repro.kernels.decode import dense_unpack
 
     _compile_kernel(dense_unpack, one_chip,
-                    ((504, stripe_rows // 32), I32), ((504, values), I32))
+                    ((504, stripe_rows // 32), I32), ((504, values), I32),
+                    name="dense_unpack")
 
 
 def test_ragged_gather_compiles(one_chip):
@@ -87,7 +95,8 @@ def test_ragged_gather_compiles(one_chip):
     from repro.kernels.decode import ragged_gather
 
     _compile_kernel(ragged_gather, one_chip,
-                    ((2004, 128), I32), ((2002, 128), I32), ((2002, 128), I32))
+                    ((2004, 128), I32), ((2002, 128), I32), ((2002, 128), I32),
+                    name="ragged_gather")
 
 
 @pytest.mark.parametrize("rows,feats,nb", [
@@ -98,7 +107,7 @@ def test_fused_transform_compiles(one_chip, rows, feats, nb):
     fused = importlib.import_module("repro.kernels.fused_transform")
     _compile_kernel(fused.fused_transform, one_chip,
                     ((rows, feats), I32), ((feats,), I32), ((feats,), I32),
-                    ((feats,), I32), ((feats, nb), F32))
+                    ((feats,), I32), ((feats, nb), F32), name="fused_transform")
 
 
 def test_embedding_bag_compiles(one_chip):

@@ -59,3 +59,36 @@ def test_stall_accounting():
 
     tr.fit(slow())
     assert tr.stall_fraction() > 0.05
+
+
+def test_step_spans_split_copy_dispatch_and_loss_wait():
+    """``train.step`` holds the batch copy and the dispatch; ``train.wait``
+    follows it around the loss read.  No span borrows the benchmark's
+    ``trainer.`` prefix."""
+    from repro.obs import Tracer
+
+    cfg = cfglib.get_smoke_config("dlrm-paper")
+    tracer = Tracer()
+    tr = Trainer(cfg, OptimizerConfig(warmup_steps=1, total_steps=3),
+                 TrainerConfig(max_steps=3), tracer=tracer)
+    tr.fit(_batches(cfg, 3))
+    spans = tracer.spans()
+    by = {}
+    for s in spans:
+        by.setdefault(s.name, []).append(s)
+    for name in ("client.stall", "train.step", "train.put", "train.dispatch",
+                 "train.wait"):
+        assert [s.labels["step"] for s in by[name]] == [1, 2, 3], name
+    assert all(s.parent == "train.step" for s in by["train.put"] + by["train.dispatch"])
+    assert all(s.parent is None for s in by["train.wait"] + by["train.step"])
+    for step, put, dispatch, wait in zip(by["train.step"], by["train.put"],
+                                         by["train.dispatch"], by["train.wait"]):
+        assert step.t0 <= put.t0 <= put.t1 <= dispatch.t0 <= dispatch.t1 <= step.t1
+        assert step.t1 <= wait.t0
+        assert all("cpu_s" in s.labels for s in (step, put, dispatch, wait))
+    assert not any(s.name.startswith("trainer.") for s in spans)
+    assert tracer.open_spans() == 0
+    # the step metrics keep their meaning: copy and dispatch, then the loss
+    for m, step in zip(tr.history, by["train.step"]):
+        assert m.step_time_s == pytest.approx(step.duration, abs=0.05)
+        assert np.isfinite(m.loss)
