@@ -57,12 +57,14 @@ class WorkerMetrics:
     rows_from_cache: int = counter()   # rows served by tensor-cache hits
     # per-engine transform accounting (mirrored from EngineStats — §7.2):
     fused_features: int = counter()            # ops served by fused kernels
-    fallback_features: int = counter()         # ops served per-feature
-    kernel_launches: int = counter()           # fused + per-feature calls
+    fallback_features: int = counter()         # ops served by numpy
+    kernel_launches: int = counter()           # fused + numpy calls
     fused_launches: int = counter()            # fused wave launches alone
     demoted_features: int = counter()          # fused ops demoted to numpy
     transform_fused_s: float = counter(0.0)    # transform_s: fused path
     transform_fallback_s: float = counter(0.0) # transform_s: numpy path
+    transform_fallback_groups: int = counter() # numpy calls over many features
+    transform_grouped_features: int = counter()  # numpy ops served grouped
     # per-engine extract accounting (mirrored from DecodeStats):
     extract_fused_s: float = counter(0.0)      # decode: batched-kernel path
     extract_fallback_s: float = counter(0.0)   # decode: per-stream path
@@ -419,6 +421,8 @@ class DPPWorker:
                 m.demoted_features = es.demoted_features
                 m.transform_fused_s = es.fused_s
                 m.transform_fallback_s = es.fallback_s
+                m.transform_fallback_groups = es.fallback_groups
+                m.transform_grouped_features = es.grouped_features
                 m.transform_launch_s = es.launch_s
 
                 # per-SPLIT label uniformity, checked at stripe arrival:

@@ -15,7 +15,10 @@ worker's production path:
     ``repro.kernels.fused_transform`` and executes the whole wave in ONE
     ``pallas_call`` (interpret mode on CPU, compiled on TPU).  Ops the
     kernel cannot express (NGram, Cartesian, MapId, FirstX, ...) fall back
-    per-feature to the numpy implementations.
+    to the numpy implementations: in each pass of consecutive fallback
+    ops, the features that share an element-wise dense op (BoxCox,
+    Logit, ...) or FirstX, with the same kwargs, run as ONE numpy call
+    over all of them; every other op runs once per feature.
 
 Both engines produce **byte-identical** environments (and therefore
 byte-identical minibatches): the SigridHash mixer is the shared 32-bit
@@ -30,7 +33,9 @@ engine-agnostic.
 kernel launches, per-path transform seconds) so Table-9-style breakdowns
 can compare engines.  Each fused wave is a ``transform.fused`` span, each
 pass of consecutive numpy ops a ``transform.fallback`` span, and each wave
-launch a ``kernel.fused_transform`` span inside its wave.
+launch a ``kernel.fused_transform`` span inside its wave.  Grouped numpy
+calls are counted in ``fallback_groups`` and the features they serve in
+``grouped_features``.
 """
 from __future__ import annotations
 
@@ -46,6 +51,7 @@ from repro.core.transforms import (
     Column,
     TransformPipeline,
     TransformSpec,
+    firstx_many,
 )
 from repro.obs import NULL_TRACER, counter, phase
 
@@ -82,9 +88,11 @@ class EngineStats:
     """Cumulative per-engine accounting (mirrored into ``WorkerMetrics``)."""
 
     fused_features: int = counter()      # op executions served by a fused kernel
-    fallback_features: int = counter()   # op executions served by per-feature numpy
+    fallback_features: int = counter()   # op executions served by numpy
     demoted_features: int = counter()    # fused-eligible ops demoted at run time
-    kernel_launches: int = counter()     # fused pallas_calls + per-feature op calls
+    kernel_launches: int = counter()     # fused pallas_calls + numpy op calls
+    fallback_groups: int = counter()     # numpy calls over several features
+    grouped_features: int = counter()    # of fallback_features: served grouped
     fused_launches: int = counter()      # fused wave launches alone
     fused_s: float = counter(0.0)        # transform_s attribution: fused path
     fallback_s: float = counter(0.0)     # transform_s attribution: numpy path
@@ -122,7 +130,8 @@ class FallbackStep:
 @dataclasses.dataclass(frozen=True)
 class CompiledPlan:
     """Ordered execution steps: each step is a FusedWave (one kernel
-    launch) or a FallbackStep (one per-feature numpy call)."""
+    launch) or a FallbackStep (one numpy op; ``group_pass`` merges a
+    pass's like ops into shared calls)."""
 
     steps: Tuple[Union[FusedWave, FallbackStep], ...]
 
@@ -190,6 +199,24 @@ def _try_fuse(spec: TransformSpec) -> Optional[FusedOp]:
     return None
 
 
+def _single_assignment(specs: Sequence[TransformSpec]) -> bool:
+    """Single-assignment check with read-before-overwrite detection: if a
+    spec's output key was already read (by an earlier spec, or by itself)
+    or already written, sequential execution order is load-bearing — an
+    earlier reader must see the PRE-overwrite value, which reordering
+    would destroy.  ``{outputs} & ({inputs} - {outputs})`` is NOT
+    sufficient: a later spec overwriting a raw batch key that an earlier
+    spec reads leaves that key out of the external set entirely."""
+    seen_inputs: set = set()
+    written: set = set()
+    for s in specs:
+        seen_inputs.update(s.inputs)       # reads happen before this write
+        if s.output in seen_inputs or s.output in written:
+            return False
+        written.add(s.output)
+    return True
+
+
 def compile_pipeline(
     specs: Sequence[TransformSpec],
 ) -> CompiledPlan:
@@ -200,20 +227,9 @@ def compile_pipeline(
     key compile to pure fallback (wave reordering would change the
     sequential-overwrite semantics of ``TransformPipeline``)."""
     specs = list(specs)
-    # single-assignment check with read-before-overwrite detection: if a
-    # spec's output key was already read (by an earlier spec, or by itself)
-    # or already written, sequential execution order is load-bearing — an
-    # earlier reader must see the PRE-overwrite value, which wave
-    # reordering would destroy.  ``{outputs} & ({inputs} - {outputs})``
-    # is NOT sufficient: a later spec overwriting a raw batch key that an
-    # earlier spec reads leaves that key out of the external set entirely.
-    seen_inputs: set = set()
-    written: set = set()
-    for s in specs:
-        seen_inputs.update(s.inputs)       # reads happen before this write
-        if s.output in seen_inputs or s.output in written:
-            return CompiledPlan(tuple(FallbackStep(s) for s in specs))
-        written.add(s.output)
+    if not _single_assignment(specs):
+        return CompiledPlan(tuple(FallbackStep(s) for s in specs))
+    written = {s.output for s in specs}
     external = {i for s in specs for i in s.inputs} - written
 
     fusable = {id(s): _try_fuse(s) for s in specs}
@@ -285,6 +301,49 @@ def decode_plan(plan: CompiledPlan) -> List[TransformSpec]:
     return out
 
 
+# Fallback ops one numpy call may serve for many features: the dense ops
+# are element-wise, so over a (features, rows) matrix they give each
+# feature's per-feature result as one row; FirstX has ``firstx_many``.
+_ELEMENTWISE = frozenset({"BoxCox", "Logit", "Clamp", "GetLocalHour"})
+
+
+def _group_key(spec: TransformSpec) -> Optional[Tuple]:
+    """Specs with equal keys may share one numpy call; None for an op
+    that runs once per feature.  Each param's type is part of the key:
+    ``0.5 == np.float64(0.5)``, but they round differently."""
+    if len(spec.inputs) != 1 or (
+        spec.op not in _ELEMENTWISE and spec.op != "FirstX"
+    ):
+        return None
+    key = (spec.op, tuple((k, type(v), v) for k, v in spec.params))
+    try:
+        hash(key)
+    except TypeError:            # array-valued params: never grouped
+        return None
+    return key
+
+
+def group_pass(
+    specs: Sequence[TransformSpec],
+) -> List[Tuple[TransformSpec, ...]]:
+    """One pass of consecutive fallback specs as numpy calls, in order:
+    the specs of one group key at the same depth in the pass (no spec
+    reads another's output) form one call, every other spec a call of
+    its own.  A spec runs after every spec of the pass whose output it
+    reads; a pass that reassigns a key keeps its sequential order."""
+    if not _single_assignment(specs):
+        return [(s,) for s in specs]
+    depth: Dict[str, int] = {}
+    calls: Dict[Tuple, List[TransformSpec]] = {}     # in order of first spec
+    for n, s in enumerate(specs):
+        d = 1 + max((depth[i] for i in s.inputs if i in depth), default=-1)
+        depth[s.output] = d
+        key = _group_key(s)
+        calls.setdefault((d, ("one", n) if key is None else key), []).append(s)
+    ordered = sorted(calls.items(), key=lambda kv: kv[0][0])   # stable
+    return [tuple(c) for _, c in ordered]
+
+
 # ---------------------------------------------------------------------------
 # Engines
 # ---------------------------------------------------------------------------
@@ -322,11 +381,14 @@ class TransformEngine:
         """Run consecutive per-feature numpy ops as one traced pass."""
         with phase(self.tracer, "transform.fallback", self.stats, "fallback_s"):
             for spec in specs:
-                fn = _OPS[spec.op]
-                env[spec.output] = fn(*[env[i] for i in spec.inputs],
-                                      **spec.kwargs)
-                self.stats.fallback_features += 1
-                self.stats.kernel_launches += 1
+                self._call(spec, env)
+
+    def _call(self, spec: TransformSpec, env: Dict[str, Column]) -> None:
+        """One per-feature numpy op."""
+        env[spec.output] = _OPS[spec.op](*[env[i] for i in spec.inputs],
+                                         **spec.kwargs)
+        self.stats.fallback_features += 1
+        self.stats.kernel_launches += 1
 
 
 class NumpyEngine(TransformEngine):
@@ -373,11 +435,15 @@ class PallasEngine(TransformEngine):
     ):
         super().__init__(pipeline)
         self.plan = compile_pipeline(pipeline.specs)
-        # the plan as passes: runs of consecutive fallback steps, and waves
-        self._passes = [
-            (fallback, tuple(steps)) for fallback, steps in itertools.groupby(
-                self.plan.steps, key=lambda st: isinstance(st, FallbackStep))
-        ]
+        # the plan as passes: runs of consecutive fallback steps, each as
+        # its numpy calls (``group_pass``), and runs of waves
+        self._passes = []
+        for fallback, steps in itertools.groupby(
+                self.plan.steps, key=lambda st: isinstance(st, FallbackStep)):
+            steps = tuple(steps)
+            if fallback:
+                steps = tuple(group_pass([st.spec for st in steps]))
+            self._passes.append((fallback, steps))
         self.block_rows = block_rows
         self.block_cols = block_cols
         self.row_quantum = max(1, row_quantum)
@@ -387,11 +453,62 @@ class PallasEngine(TransformEngine):
         env = self._seed_env(batch)
         for fallback, steps in self._passes:
             if fallback:
-                self._fallback_pass([st.spec for st in steps], env)
+                self._grouped_pass(steps, env)
             else:
                 for wave in steps:
                     self._run_wave(wave, env)
         return env
+
+    # -- grouped numpy passes -----------------------------------------------
+
+    def _grouped_pass(self, calls: Sequence[Tuple[TransformSpec, ...]],
+                      env: Dict[str, Column]) -> None:
+        """Run one pass of fallback ops, each group of specs as one numpy
+        call where its inputs allow (``_run_group``)."""
+        with phase(self.tracer, "transform.fallback", self.stats, "fallback_s"):
+            for specs in calls:
+                if len(specs) == 1:
+                    self._call(specs[0], env)
+                else:
+                    self._run_group(specs, env)
+
+    def _run_group(self, specs: Sequence[TransformSpec],
+                   env: Dict[str, Column]) -> None:
+        """One op over several features.  The inputs are bucketed by what
+        the grouped call needs to give each feature's per-feature bits:
+        dense, 1-D float32 of one length; FirstX, ``SparseColumn``s of one
+        row count, values dtype and scores dtype.  A bucket of two or more
+        is one call; every other feature runs per feature."""
+        firstx = specs[0].op == "FirstX"
+        buckets: Dict[Any, List[TransformSpec]] = {}
+        for n, spec in enumerate(specs):
+            col = env[spec.inputs[0]]
+            if firstx and isinstance(col, SparseColumn):
+                key = (col.rows, col.values.dtype,
+                       None if col.scores is None else col.scores.dtype)
+            elif not firstx and isinstance(col, np.ndarray) \
+                    and col.ndim == 1 and col.dtype == np.float32:
+                key = len(col)
+            else:
+                key = ("one", n)
+            buckets.setdefault(key, []).append(spec)
+        kwargs = specs[0].kwargs
+        for members in buckets.values():
+            if len(members) == 1:
+                self._call(members[0], env)
+                continue
+            cols = [env[s.inputs[0]] for s in members]
+            if firstx:
+                outs = firstx_many(cols, **kwargs)
+            else:
+                # features-major: each output is a contiguous row view
+                outs = _OPS[specs[0].op](np.stack(cols), **kwargs)
+            for s, out in zip(members, outs):
+                env[s.output] = out
+            self.stats.fallback_features += len(members)
+            self.stats.grouped_features += len(members)
+            self.stats.fallback_groups += 1
+            self.stats.kernel_launches += 1
 
     # -- wave execution -----------------------------------------------------
 

@@ -138,6 +138,37 @@ def firstx(col: SparseColumn, x: int) -> SparseColumn:
     )
 
 
+def firstx_many(cols: Sequence[SparseColumn], x: int) -> List[SparseColumn]:
+    """``firstx`` over several columns of one row count in one pass: one
+    2-D ``minimum``/``cumsum`` over the stacked offsets and one gather over
+    the concatenated values (and scores).  The columns share a values
+    dtype and either all carry scores or none; each output's offsets,
+    values and scores are views of the shared results."""
+    k = len(cols)
+    offsets = np.stack([c.offsets for c in cols])               # (k, rows+1)
+    lengths = np.minimum(np.diff(offsets, axis=1), x)
+    new_off = np.zeros(offsets.shape, np.int64)
+    np.cumsum(lengths, axis=1, out=new_off[:, 1:])
+    # each column's first value in the concatenation
+    nnz = np.array([len(c.values) for c in cols], np.int64)
+    base = np.cumsum(nnz) - nnz
+    idx = _ragged_gather((offsets[:, :-1] + base[:, None]).ravel(),
+                         lengths.ravel())
+    values = np.concatenate([c.values for c in cols])[idx]
+    scores = (np.concatenate([c.scores for c in cols])[idx]
+              if cols[0].scores is not None else None)
+    bounds = np.zeros(k + 1, np.int64)
+    np.cumsum(new_off[:, -1], out=bounds[1:])
+    return [
+        SparseColumn(
+            offsets=new_off[j],
+            values=values[bounds[j]: bounds[j + 1]],
+            scores=scores[bounds[j]: bounds[j + 1]] if scores is not None else None,
+        )
+        for j in range(k)
+    ]
+
+
 def _ragged_gather(starts: np.ndarray, lengths: np.ndarray) -> np.ndarray:
     """Indices selecting, for each row i, ``lengths[i]`` consecutive source
     elements beginning at ``starts[i]``."""

@@ -12,8 +12,9 @@ plus an ``ALL`` aggregate:
     the remainder as compute.  Shares sum to 100 by construction.
   * bytes by source tier (storage vs stripe-cache RX, DRAM/flash
     resident), the over-read factor (stripe rows decoded per fresh row
-    served — Table 9's E-stage amplification) and the fused-kernel
-    fraction of transform time.
+    served — Table 9's E-stage amplification), the fused-kernel
+    fraction of transform time, and the numpy fallback's grouped calls
+    and the features they served.
 
 ``--check`` validates the artifact structurally (the schema Perfetto
 loads: complete ``X`` events, sorted non-negative timestamps, no span
@@ -57,6 +58,7 @@ _SNAP_COLS = (
     "worker.storage_rx_bytes", "worker.cache_rx_bytes",
     "worker.rows_decoded", "worker.rows_done", "worker.rows_from_cache",
     "worker.transform_fused_s", "worker.transform_fallback_s",
+    "worker.transform_fallback_groups", "worker.transform_grouped_features",
 )
 
 
@@ -130,6 +132,9 @@ def _metric_cols(snap: Dict[str, float],
         "flash_bytes_stored": float(cache.get("flash_bytes_stored", 0.0)),
         "over_read": decoded / fresh if fresh > 0 else 1.0,
         "fused_frac": tf / (tf + tb) if (tf + tb) > 0.0 else 0.0,
+        "fallback_groups": float(snap.get("worker.transform_fallback_groups", 0)),
+        "grouped_features": float(
+            snap.get("worker.transform_grouped_features", 0)),
     }
 
 
@@ -229,7 +234,8 @@ def _fmt_table(rows: Dict[str, Dict[str, float]]) -> str:
         )
     head2 = (
         f"{'tenant':<12} {'storage_rx':>12} {'cache_rx':>12} "
-        f"{'dram_res':>10} {'flash_res':>10} {'over_read':>9} {'fused':>6}"
+        f"{'dram_res':>10} {'flash_res':>10} {'over_read':>9} {'fused':>6} "
+        f"{'groups':>8} {'grouped':>9}"
     )
     lines += ["", head2, "-" * len(head2)]
     for tenant, r in rows.items():
@@ -238,7 +244,8 @@ def _fmt_table(rows: Dict[str, Dict[str, float]]) -> str:
             f"{int(r['cache_rx_bytes']):>12} "
             f"{int(r['dram_bytes_stored']):>10} "
             f"{int(r['flash_bytes_stored']):>10} "
-            f"{r['over_read']:>9.2f} {r['fused_frac']:>6.2f}"
+            f"{r['over_read']:>9.2f} {r['fused_frac']:>6.2f} "
+            f"{int(r['fallback_groups']):>8} {int(r['grouped_features']):>9}"
         )
     return "\n".join(lines)
 

@@ -378,6 +378,163 @@ def test_default_dlrm_pipeline_differential(rng):
     assert pe.stats.kernel_launches < ne.stats.kernel_launches
 
 
+# -- grouped fallback passes --------------------------------------------------
+
+
+def _shaped_batch(rng, rows, n_dense, n_sparse, one_hot=False, scored_every=5):
+    """A stripe shaped like the benchmark's tables: NaN-holed N(0, 1) dense
+    columns; sparse columns with empty rows, every ``scored_every``-th
+    carrying scores (none when one-hot)."""
+    dense = {}
+    for fid in range(n_dense):
+        col = rng.normal(0, 1, rows).astype(np.float32)
+        col[rng.random(rows) < 0.3] = np.nan
+        dense[fid] = col
+    sparse = {}
+    for j in range(n_sparse):
+        present = rng.random(rows) < 0.7
+        if one_hot:
+            lengths = present.astype(np.int64)
+        else:
+            lengths = np.where(present, rng.integers(1, 60, rows), 0)
+        lists = [rng.integers(0, 10 ** 6, n).tolist() for n in lengths]
+        scores = ([rng.random(n).tolist() for n in lengths]
+                  if not one_hot and j % scored_every == 0 else None)
+        sparse[n_dense + j] = _col(lists, scores)
+    return ColumnBatch(num_rows=rows, dense=dense, sparse=sparse)
+
+
+def _paper_case(rng):
+    """dlrm-paper's DAG: 504 dense (168 each of BoxCox, Logit, Clamp), 32
+    raw sparse (FirstX 32, then SigridHash), 10 derived (4 NGram, 3
+    Cartesian, 3 Bucketize).  Per stripe: BoxCox, Logit, and FirstX split
+    by scores, 4 groups serving 368 features; NGram and Cartesian stay
+    per feature."""
+    batch = _shaped_batch(rng, 512, 504, 32)
+    pipe = T.default_dlrm_pipeline(range(504), range(504, 536),
+                                   hash_size=125_000, firstx=32, n_derived=10)
+    return pipe.specs, batch, (4, 368, 375)
+
+
+def _criteo_case(rng):
+    """dlrm-criteo-1tb's DAG: 13 dense (5 BoxCox, 4 Logit, 4 Clamp), 26
+    one-hot sparse: 3 groups serving all 35 fallback features."""
+    batch = _shaped_batch(rng, 512, 13, 26, one_hot=True)
+    pipe = T.default_dlrm_pipeline(range(13), range(13, 39),
+                                   hash_size=200_000, firstx=1)
+    return pipe.specs, batch, (3, 35, 35)
+
+
+def _singletons_case(rng):
+    """Each group key has one spec: every op runs per feature."""
+    batch = _shaped_batch(rng, 37, 2, 1)
+    specs = [
+        TransformSpec("BoxCox", ("f0",), "a", ()),
+        TransformSpec("Logit", ("f1",), "b", ()),
+        TransformSpec("FirstX", ("f2",), "c", (("x", 4),)),
+        TransformSpec("BoxCox", ("f1",), "d", (("lmbda", 0.25),)),
+    ]
+    return specs, batch, (0, 0, 4)
+
+
+def _demoting_case(rng):
+    """Inputs that a grouped call could not serve bit for bit run per
+    feature: float64 and differently sized dense columns, a FirstX input
+    with another row count.  The conforming rest still groups."""
+    batch = _shaped_batch(rng, 19, 6, 3, scored_every=1)
+    batch.dense[1] = batch.dense[1].astype(np.float64)
+    batch.dense[2] = batch.dense[2][:11]
+    batch.sparse[8] = _col([[1, 2, 3], [], [4]])
+    specs = [TransformSpec("BoxCox", (f"f{i}",), f"d{i}", ()) for i in range(6)]
+    specs += [TransformSpec("FirstX", (f"f{i}",), f"t{i}", (("x", 2),))
+              for i in (6, 7, 8)]
+    # BoxCox: f0, f3, f4, f5 grouped; FirstX: f6, f7 grouped
+    return specs, batch, (2, 6, 9)
+
+
+def _other_elementwise_case(rng):
+    """The other element-wise dense fallbacks group too: Clamp with
+    bounds float32 cannot hold (never fused), and GetLocalHour."""
+    batch = _shaped_batch(rng, 23, 5, 0)
+    for fid in (3, 4):
+        batch.dense[fid] = (batch.dense[fid] * 1e5).astype(np.float32)
+    specs = [TransformSpec("Clamp", (f"f{i}",), f"c{i}",
+                           (("lo", -0.1), ("hi", 0.7))) for i in range(3)]
+    specs += [TransformSpec("GetLocalHour", (f"f{i}",), f"h{i}",
+                            (("tz_offset_s", -3600),)) for i in (3, 4)]
+    return specs, batch, (2, 5, 5)
+
+
+_GROUP_CASES = {
+    "paper": _paper_case,
+    "criteo": _criteo_case,
+    "group_of_one": _singletons_case,
+    "demotes": _demoting_case,
+    "other_elementwise": _other_elementwise_case,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GROUP_CASES))
+def test_grouped_fallback_byte_identical(case):
+    """PallasEngine's grouped numpy calls give the per-feature NumpyEngine
+    bits, in every case; its per-feature path stays the reference."""
+    specs, batch, _ = _GROUP_CASES[case](np.random.default_rng(14))
+    ne, pe = _assert_engines_identical(specs, batch, use_pallas=None)
+    assert ne.stats.fallback_groups == ne.stats.grouped_features == 0
+    assert ne.stats.kernel_launches == len(specs)
+
+
+@pytest.mark.parametrize("case", sorted(_GROUP_CASES))
+def test_grouped_fallback_counters(case):
+    """Per stripe: grouped calls, the features they serve, and every
+    fallback feature still counted; a grouped call is one launch."""
+    specs, batch, (groups, grouped, fallback) = _GROUP_CASES[case](
+        np.random.default_rng(15))
+    pe = PallasEngine(TransformPipeline(list(specs)))
+    pe.run(batch)
+    s = pe.stats
+    assert (s.fallback_groups, s.grouped_features, s.fallback_features) == (
+        groups, grouped, fallback)
+    assert s.kernel_launches == (
+        s.fallback_features - s.grouped_features + s.fallback_groups
+        + s.fused_launches)
+
+
+@pytest.mark.parametrize("x", [1, 3, 32])
+@pytest.mark.parametrize("scored", [False, True])
+def test_firstx_many_matches_firstx(x, scored):
+    rng = np.random.default_rng(x)
+    cols = []
+    for n in (0, 5, 40, 1):         # an empty column among them
+        lengths = rng.integers(0, 50, 6) if n else np.zeros(6, np.int64)
+        lists = [rng.integers(-10 ** 9, 10 ** 9, k).tolist() for k in lengths]
+        scores = [rng.random(k).tolist() for k in lengths] if scored else None
+        cols.append(_col(lists, scores))
+    got = T.firstx_many(cols, x)
+    assert len(got) == len(cols)
+    for col, out in zip(cols, got):
+        _assert_column_identical(T.firstx(col, x), out)
+
+
+def test_group_pass_orders_dependent_specs():
+    """A spec reading a grouped output runs after the group; like specs
+    at one depth share a call; a reassigning pass keeps its order."""
+    from repro.core.engine import group_pass
+
+    fx = lambda i, o: TransformSpec("FirstX", (i,), o, (("x", 2),))
+    specs = [fx("f0", "a"), fx("a", "b"), TransformSpec("NGram", ("b",), "g"),
+             fx("f1", "c"), fx("c", "d")]
+    calls = group_pass(specs)
+    assert [[s.output for s in c] for c in calls] == [["a", "c"], ["b", "d"],
+                                                      ["g"]]
+    reassign = [fx("f0", "a"), fx("a", "a")]
+    assert group_pass(reassign) == [(reassign[0],), (reassign[1],)]
+    # equal params of different types round differently: never one call
+    box = [TransformSpec("BoxCox", ("f0",), "p", (("lmbda", 0.5),)),
+           TransformSpec("BoxCox", ("f1",), "q", (("lmbda", np.float64(0.5)),))]
+    assert len(group_pass(box)) == 2
+
+
 # -- engine construction ------------------------------------------------------
 
 

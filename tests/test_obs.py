@@ -536,6 +536,8 @@ def test_report_metric_columns_from_snapshot_payload():
                 "worker.rows_from_cache": 50,
                 "worker.transform_fused_s": 3.0,
                 "worker.transform_fallback_s": 1.0,
+                "worker.transform_fallback_groups": 4,
+                "worker.transform_grouped_features": 368,
             }},
             "cache": {"a": {"dram_bytes_stored": 42.0,
                             "flash_bytes_stored": 7.0}},
@@ -546,6 +548,7 @@ def test_report_metric_columns_from_snapshot_payload():
     assert r["cache_rx_bytes"] == 250.0
     assert r["over_read"] == pytest.approx(300 / 150)
     assert r["fused_frac"] == pytest.approx(0.75)
+    assert r["fallback_groups"] == 4.0 and r["grouped_features"] == 368.0
     assert r["dram_bytes_stored"] == 42.0 and r["flash_bytes_stored"] == 7.0
 
 
