@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import json
 from pathlib import Path
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -20,17 +20,50 @@ def peaks(device_kind: str) -> Dict:
     return table[device_kind]
 
 
+def bag_sizes(model: Dict) -> Optional[List[int]]:
+    """The published ids per bag of each table, or None where the
+    configuration gives none (lengths then vary, up to
+    ``max_ids_per_feature``).  The list must name every table, and its
+    largest bag must be ``max_ids_per_feature``."""
+    if "bag_sizes" not in model:
+        return None
+    sizes = [int(s) for s in model["bag_sizes"]]
+    if len(sizes) != model["num_tables"] or min(sizes) < 1:
+        raise ValueError(f"bag_sizes must give each of the {model['num_tables']} "
+                         f"tables at least one id; got {sizes}")
+    if model["max_ids_per_feature"] != max(sizes):
+        raise ValueError(f"max_ids_per_feature {model['max_ids_per_feature']} must "
+                         f"equal max(bag_sizes) {max(sizes)}")
+    return sizes
+
+
 def model_flops_per_sample(model: Dict) -> float:
-    """Forward plus backward of the bottom and top MLPs, the pairwise
-    interaction (the upper triangle the model uses) and the bag pooling,
-    per sample.  Backward counts twice the forward (the input and the
-    weight gradients).  The optimizer is not counted."""
+    """Forward plus backward of the bottom and top MLPs, the interaction
+    and the bag pooling, per sample.  The interaction is ``"dot"`` (the
+    default: pairwise dots of the bottom output and the pooled bags, the
+    upper triangle the model uses, ahead of the bottom output in the top
+    MLP's input) or ``"dcn"`` (a low-rank DCNv2 cross network over their
+    concatenation, d = (T+1)E wide: per layer the matmuls d -> r -> d,
+    and the top MLP reads the d-wide output).  Pooling adds every id of a
+    bag: ``bag_sizes`` where the configuration gives them, else T bags of
+    ``max_ids_per_feature``.  Matmuls count 2ab; biases and element-wise
+    ops are left out.  Backward counts twice the forward (the input and
+    the weight gradients).  The optimizer is not counted."""
     t, e, l = model["num_tables"], model["embed_dim"], model["max_ids_per_feature"]
+    kind = model.get("interaction", "dot")
+    if kind == "dot":
+        top_in = model["bottom_mlp"][-1] + (t + 1) * t // 2
+        interaction = 2 * e * (t + 1) * t // 2
+    elif kind == "dcn":
+        top_in = (t + 1) * e
+        interaction = model["dcn_layers"] * 4 * top_in * model["dcn_low_rank"]
+    else:
+        raise ValueError(f"interaction must be 'dot' or 'dcn'; got {kind!r}")
     bottom = [model["num_dense"]] + list(model["bottom_mlp"])
-    top = [model["bottom_mlp"][-1] + (t + 1) * t // 2] + list(model["top_mlp"])
+    top = [top_in] + list(model["top_mlp"])
     mlp = sum(2 * a * b for dims in (bottom, top) for a, b in zip(dims[:-1], dims[1:]))
-    interaction = 2 * e * (t + 1) * t // 2
-    pooling = 2 * t * l * e
+    sizes = bag_sizes(model)
+    pooling = 2 * e * (sum(sizes) if sizes else t * l)
     return 3.0 * (mlp + interaction + pooling)
 
 

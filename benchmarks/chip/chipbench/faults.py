@@ -38,7 +38,8 @@ def _id_altered():
 
     def alter(*a, **kw):
         out = real(*a, **kw)
-        out["sparse_ids"][0, 0, 0] += 1
+        ids = out["sparse_ids"]          # padded (B, T, L) or packed (B, ids)
+        ids[(0,) * ids.ndim] += 1
         return out
     return worker, "materialize_dlrm_batch", alter
 

@@ -251,7 +251,7 @@ def reference_batches(cell: Cell, pool) -> Dict[str, Dict[str, np.ndarray]]:
             r = cell.reference.transform_rows(
                 raw, lo, lo + batch, pool.job,
                 cell.config["model"]["max_ids_per_feature"])
-            refs[check.batch_key(r)] = r
+            refs[check.batch_key(r, cell.config["model"])] = r
     return refs
 
 
@@ -323,7 +323,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     # through: a cold compile can hold one back past the warm steps, and
     # its kernels would then compile inside the window.
     for _ in range(pool.batches):
-        if not live or len({check.batch_key(b) for b in feed.handed}) >= pool.batches:
+        if not live or len({check.batch_key(b, model)
+                            for b in feed.handed}) >= pool.batches:
             break
         trainer.cfg.max_steps += 1
         state = trainer.fit(feed, state)
@@ -396,9 +397,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     refs = reference_batches(cell, pool)
     compared = made if not live else feed.handed
     data_numbers, bad = check.compare_batches(
-        compared, refs, cfg["limits"]["dense_gap"])
-    failed_keys = {check.batch_key(b) for b, f in zip(compared, bad) if f}
-    firsts = [refs.get(check.batch_key(b)) for b in feed.handed[:CHECK_STEPS]]
+        compared, refs, cfg["limits"]["dense_gap"], model)
+    failed_keys = {check.batch_key(b, model) for b, f in zip(compared, bad) if f}
+    firsts = [refs.get(check.batch_key(b, model)) for b in feed.handed[:CHECK_STEPS]]
     if all(r is not None for r in firsts):
         ref_read = ref.train_readings(model, cfg["optimizer"], seed, firsts, jnp.float32)
         numbers = check.train_numbers(prog, ref_read)
@@ -410,7 +411,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     correct, checked = check.verdict(numbers, cfg["limits"])
     t_ref = time.perf_counter() - t_ref
     window_failed = sum(len(b["label"]) for b in window_batches
-                        if check.batch_key(b) in failed_keys)
+                        if check.batch_key(b, model) in failed_keys)
     failed = window_failed + bad_rows
 
     # -- report -----------------------------------------------------------------------------

@@ -17,6 +17,13 @@ def tiny(cell):
     c["table"].update(stored_dense=6 if one_hot else 24,
                       stored_sparse=5 if one_hot else 12,
                       derived=0 if one_hot else 3)
+    m = c["model"]
+    if "bag_sizes" in m:          # fixed multi-hot: one stored feature per table
+        bags = [min(b, m["max_ids_per_feature"]) for b in m["bag_sizes"][:m["num_tables"]]]
+        m.update(bag_sizes=bags, max_ids_per_feature=max(bags))
+        c["table"].update(stored_sparse=len(bags), derived=0)
+    if "dcn_low_rank" in m:
+        m["dcn_low_rank"] = 8
     c["batch_size"] = 128 if one_hot else 64
     c["stripe_rows"] = 64
     cell.config = c

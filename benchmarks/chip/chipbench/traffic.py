@@ -6,7 +6,9 @@ the program's data generation cannot move the yardstick.  The draws
 follow the program's synthetic warehouse (``make_schema`` and
 ``generate_partition``): per feature a coverage, a mean list length and a
 cardinality; dense values N(0, 1), NaN where absent; Poisson list lengths,
-Zipf ids; a 3% click rate.
+Zipf ids; a 3% click rate.  A configuration whose model gives
+``bag_sizes`` (fixed multi-hot inputs) has every row hold exactly that
+many ids in each table's feature, drawn as the others are.
 
 Every seed sees the same pool of rows, and so the same stripe sizes and
 the same compiled kernel shapes; the seed sets the order in which the
@@ -16,10 +18,11 @@ the traffic file's ``data_seed``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from chipbench import cost
 from repro.core import dwrf
 from repro.core.dpp import SessionSpec
 from repro.core.schema import (
@@ -39,10 +42,30 @@ class Feature:
     coverage: float
     avg_length: float
     cardinality: int
+    bag_size: int = 0     # ids in every row, where fixed; 0: Poisson lengths
 
 
-def draw_features(table: Dict, seed: int) -> List[Feature]:
-    """The stored table's features: dense first, then sparse."""
+def fixed_bags(config: Dict) -> Optional[List[int]]:
+    """The model's ``bag_sizes``, checked against the stored table: each
+    table is then one raw sparse feature the job reads, every stored
+    sparse feature, with no derived or one-hot features.  None where the
+    model gives no sizes."""
+    sizes = cost.bag_sizes(config["model"])
+    table = config["table"]
+    if sizes is not None and (table["derived"] != 0 or table["one_hot"]
+                              or table["stored_sparse"] != len(sizes)):
+        raise ValueError(
+            "bag_sizes needs table.derived 0, table.one_hot false and "
+            f"table.stored_sparse {len(sizes)} (num_tables); got derived "
+            f"{table['derived']}, one_hot {table['one_hot']}, stored_sparse "
+            f"{table['stored_sparse']}")
+    return sizes
+
+
+def draw_features(table: Dict, seed: int,
+                  bag_sizes: Optional[List[int]] = None) -> List[Feature]:
+    """The stored table's features: dense first, then sparse; sparse
+    feature t holds ``bag_sizes[t]`` ids in every row where given."""
     rng = np.random.default_rng(seed)
     out: List[Feature] = []
     n_dense, n_sparse = table["stored_dense"], table["stored_sparse"]
@@ -56,7 +79,10 @@ def draw_features(table: Dict, seed: int) -> List[Feature]:
             kind = "scored" if rng.random() < table["scored_share"] else "sparse"
             if table["one_hot"]:
                 coverage, avg_length = 1.0, 1.0
-        out.append(Feature(fid, kind, coverage, avg_length, cardinality))
+        bag = bag_sizes[fid - n_dense] if bag_sizes and fid >= n_dense else 0
+        if bag:
+            coverage, avg_length = 1.0, float(bag)
+        out.append(Feature(fid, kind, coverage, avg_length, cardinality, bag))
     return out
 
 
@@ -75,6 +101,8 @@ def draw_partition(features: List[Feature], table: Dict, rows: int,
             continue
         if table["one_hot"]:
             lengths = present.astype(np.int64)
+        elif f.bag_size:
+            lengths = np.full(rows, f.bag_size, np.int64)
         else:
             cap = 4 * int(f.avg_length) + 4
             lengths = np.where(present, np.clip(rng.poisson(f.avg_length, rows), 1, cap),
@@ -187,7 +215,7 @@ def make_pool(config: Dict, traffic: Dict) -> Pool:
     table_cfg = config["table"]
     batch = config["batch_size"]
     seed = traffic["data_seed"]
-    features = draw_features(table_cfg, seed)
+    features = draw_features(table_cfg, seed, fixed_bags(config))
     rows = traffic["partition_batches"] * batch
     n_parts = traffic["pool_batches"] // traffic["partition_batches"]
     raw = [draw_partition(features, table_cfg, rows, seed, p) for p in range(n_parts)]

@@ -62,8 +62,10 @@ def readings(cell, seeds, require_tpu: bool = True, fault: str = ""):
                 if src.session is not None:
                     src.session.stop()
         state = None
-        data, _ = check.compare_batches(src.feed.handed, refs, cfg["limits"]["dense_gap"])
-        firsts = [refs[check.batch_key(b)] for b in src.feed.handed[:harness.CHECK_STEPS]]
+        data, _ = check.compare_batches(src.feed.handed, refs, cfg["limits"]["dense_gap"],
+                                        model)
+        firsts = [refs[check.batch_key(b, model)]
+                  for b in src.feed.handed[:harness.CHECK_STEPS]]
         f32 = ref.train_readings(model, cfg["optimizer"], seed, firsts, jnp.float32)
         low = ref.train_readings(model, cfg["optimizer"], seed, firsts, jnp.bfloat16)
         yield {"seed": seed, "program": {**check.train_numbers(prog, f32), **data},
@@ -91,7 +93,7 @@ def data_readings(cell, data_seeds, require_tpu: bool = True):
         pool = traffic.make_pool(c.config, c.traffic)
         src = harness.start_source(c, pool, ds, 0.0, NULL_TRACER, False)
         data, _ = check.compare_batches(src.made, harness.reference_batches(c, pool),
-                                        c.config["limits"]["dense_gap"])
+                                        c.config["limits"]["dense_gap"], c.config["model"])
         made_rows = sum(len(b["label"]) for b in src.made)
         data["rows_unaccounted"] = (abs(sum(r["rows"] for r in pool.raw) - made_rows)
                                     + abs(src.produced_rows - made_rows)
