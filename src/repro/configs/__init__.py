@@ -35,6 +35,9 @@ _MODULES = {
     "kimi-k2-1t-a32b": "kimi_k2_1t",
     "seamless-m4t-large-v2": "seamless_m4t_v2",
     "dlrm-paper": "dlrm_paper",
+    # reached by name (the launcher's --arch); not one of the dry-run's
+    # assigned architectures above
+    "dlrm-dcnv2": "dlrm_dcnv2",
 }
 
 

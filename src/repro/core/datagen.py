@@ -48,11 +48,14 @@ def generate_partition(
             col[~present] = np.nan
             dense[f.fid] = col
         else:
-            lengths = np.where(
-                present,
-                np.clip(rng.poisson(f.avg_length, n), 1, 4 * int(f.avg_length) + 4),
-                0,
-            ).astype(np.int64)
+            if f.bag_size:
+                lengths = np.where(present, f.bag_size, 0).astype(np.int64)
+            else:
+                lengths = np.where(
+                    present,
+                    np.clip(rng.poisson(f.avg_length, n), 1, 4 * int(f.avg_length) + 4),
+                    0,
+                ).astype(np.int64)
             offsets = np.zeros(n + 1, np.int64)
             np.cumsum(lengths, out=offsets[1:])
             nnz = int(offsets[-1])

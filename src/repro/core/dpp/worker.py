@@ -81,6 +81,10 @@ class WorkerMetrics:
     # transform_s and load_s: below busy_s when a thread waits or is
     # kept off the interpreter lock
     cpu_s: float = counter(0.0)
+    # load phase: the padded batches' id slots (rows x max_ids per sparse
+    # key) and the ids that fill them; the rest is padding
+    load_id_slots: int = counter()
+    load_ids: int = counter()
     # per-extent I/O sizes of this worker's stripe fetches (Table 6)
     io_sizes: List[int] = counter(factory=list)
 
@@ -362,6 +366,7 @@ class DPPWorker:
                 self.spec.sparse_keys,
                 self.spec.max_ids_per_feature,
                 labels=labels[start:stop] if labels is not None else None,
+                counts=m,
             )
             out.append(tensors)
 

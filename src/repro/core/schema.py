@@ -40,6 +40,7 @@ class FeatureDef:
     avg_length: float = 26.0          # Table 5: avg sparse length ~20-26
     cardinality: int = 100_000        # id space for sparse values
     popularity: float = 1.0           # read-popularity weight (drives Fig.7)
+    bag_size: int = 0                 # ids in every present row (fixed multi-hot); 0: varies
 
     @property
     def logged(self) -> bool:

@@ -383,8 +383,13 @@ def materialize_dlrm_batch(
     sparse_keys: Sequence[str],
     max_ids: int,
     labels: Optional[np.ndarray] = None,
+    counts: Optional[Any] = None,
 ) -> Dict[str, np.ndarray]:
-    """Batch transformed features into the DLRM tensor format (load phase)."""
+    """Batch transformed features into the DLRM tensor format (load phase).
+
+    ``counts``, where given (a ``WorkerMetrics``), gains the batch's id
+    slots (``load_id_slots``: rows x ``max_ids`` per sparse key) and the
+    ids that fill them (``load_ids``)."""
     rows = None
     dense_cols = []
     for k in dense_keys:
@@ -412,6 +417,9 @@ def materialize_dlrm_batch(
         )
         ids[r_idx, c_idx] = col.values[take]
         mask[r_idx, c_idx] = 1.0
+        if counts is not None:
+            counts.load_id_slots += rows * max_ids
+            counts.load_ids += len(take)
         sp_ids.append(ids)
         sp_mask.append(mask)
 

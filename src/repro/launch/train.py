@@ -2,6 +2,7 @@
 
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch dlrm-paper --steps 50 --smoke
+  PYTHONPATH=src python -m repro.launch.train --arch dlrm-dcnv2 --steps 50 --smoke
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-8b --steps 20 --smoke
 
 DLRM runs the full paper pipeline (synthetic warehouse partitions -> DPP
@@ -48,6 +49,13 @@ def dlrm_dpp_batches(cfg: DLRMConfig, batch_size: int, n_partitions: int = 2,
 
     schema = make_schema("dlrm_table", n_dense=cfg.num_dense * 3,
                          n_sparse=max(cfg.num_tables * 3, 8), seed=0)
+    # fixed multi-hot bags: each table reads one raw feature that holds
+    # exactly its bag size in every row, and no feature is derived
+    n_gen = 0 if cfg.bag_sizes else max(cfg.num_tables // 4, 0)
+    sparse = schema.sparse_ids[: cfg.num_tables - n_gen]
+    for fid, size in zip(sparse, cfg.bag_sizes):
+        f = schema.features[fid]
+        f.coverage, f.avg_length, f.bag_size = 1.0, float(size), size
     wh = Warehouse()
     table = wh.create_table(schema)
     table.generate(
@@ -56,8 +64,6 @@ def dlrm_dpp_batches(cfg: DLRMConfig, batch_size: int, n_partitions: int = 2,
         dwrf.DwrfWriterOptions(flattened=True, stripe_rows=STRIPE_ROWS),
     )
     dense = schema.dense_ids[: cfg.num_dense]
-    n_gen = max(cfg.num_tables // 4, 0)
-    sparse = schema.sparse_ids[: cfg.num_tables - n_gen]
     pipe = default_dlrm_pipeline(
         dense, sparse, hash_size=cfg.vocab_per_table,
         firstx=cfg.max_ids_per_feature, n_derived=n_gen,

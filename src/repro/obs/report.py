@@ -13,8 +13,9 @@ plus an ``ALL`` aggregate:
   * bytes by source tier (storage vs stripe-cache RX, DRAM/flash
     resident), the over-read factor (stripe rows decoded per fresh row
     served — Table 9's E-stage amplification), the fused-kernel
-    fraction of transform time, and the numpy fallback's grouped calls
-    and the features they served.
+    fraction of transform time, the numpy fallback's grouped calls
+    and the features they served, and the load phase's id slots, ids and
+    padding share (slots no id fills).
 
 ``--check`` validates the artifact structurally (the schema Perfetto
 loads: complete ``X`` events, sorted non-negative timestamps, no span
@@ -59,6 +60,7 @@ _SNAP_COLS = (
     "worker.rows_decoded", "worker.rows_done", "worker.rows_from_cache",
     "worker.transform_fused_s", "worker.transform_fallback_s",
     "worker.transform_fallback_groups", "worker.transform_grouped_features",
+    "worker.load_id_slots", "worker.load_ids",
 )
 
 
@@ -125,6 +127,8 @@ def _metric_cols(snap: Dict[str, float],
     decoded = snap.get("worker.rows_decoded", 0)
     tf = snap.get("worker.transform_fused_s", 0.0)
     tb = snap.get("worker.transform_fallback_s", 0.0)
+    slots = float(snap.get("worker.load_id_slots", 0))
+    ids = float(snap.get("worker.load_ids", 0))
     return {
         "storage_rx_bytes": float(snap.get("worker.storage_rx_bytes", 0)),
         "cache_rx_bytes": float(snap.get("worker.cache_rx_bytes", 0)),
@@ -135,6 +139,9 @@ def _metric_cols(snap: Dict[str, float],
         "fallback_groups": float(snap.get("worker.transform_fallback_groups", 0)),
         "grouped_features": float(
             snap.get("worker.transform_grouped_features", 0)),
+        "load_id_slots": slots,
+        "load_ids": ids,
+        "load_pad_frac": 1.0 - ids / slots if slots > 0 else 0.0,
     }
 
 
@@ -235,7 +242,7 @@ def _fmt_table(rows: Dict[str, Dict[str, float]]) -> str:
     head2 = (
         f"{'tenant':<12} {'storage_rx':>12} {'cache_rx':>12} "
         f"{'dram_res':>10} {'flash_res':>10} {'over_read':>9} {'fused':>6} "
-        f"{'groups':>8} {'grouped':>9}"
+        f"{'groups':>8} {'grouped':>9} {'load_pad':>8}"
     )
     lines += ["", head2, "-" * len(head2)]
     for tenant, r in rows.items():
@@ -245,7 +252,8 @@ def _fmt_table(rows: Dict[str, Dict[str, float]]) -> str:
             f"{int(r['dram_bytes_stored']):>10} "
             f"{int(r['flash_bytes_stored']):>10} "
             f"{r['over_read']:>9.2f} {r['fused_frac']:>6.2f} "
-            f"{int(r['fallback_groups']):>8} {int(r['grouped_features']):>9}"
+            f"{int(r['fallback_groups']):>8} {int(r['grouped_features']):>9} "
+            f"{r['load_pad_frac']:>8.2f}"
         )
     return "\n".join(lines)
 

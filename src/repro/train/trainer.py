@@ -81,6 +81,39 @@ class TrainMetrics:
     embed_fetch_s: float = counter(0.0)
 
 
+class BagPacker:
+    """Packs a padded DPP batch for a model with fixed multi-hot bags
+    (``bag_sizes``): ``sparse_ids`` (B, T, L) becomes (B, sum of the
+    sizes), table t's ids in its own columns, and ``sparse_mask`` is
+    dropped once it is checked to hold exactly each table's size in every
+    row.  Returns a new dict; the one handed in is left as it was."""
+
+    def __init__(self, model_cfg: Any):
+        from repro.models.dlrm import bag_columns
+
+        self.sizes = model_cfg.bag_sizes
+        self.width = model_cfg.max_ids_per_feature
+        self.columns = bag_columns(model_cfg)
+        self.pattern = np.zeros(len(self.sizes) * self.width, np.float32)
+        self.pattern[self.columns] = 1.0
+
+    def __call__(self, batch: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+        ids = np.asarray(batch["sparse_ids"])
+        rows = ids.shape[0]
+        mask = np.asarray(batch["sparse_mask"])
+        if mask.shape != ids.shape or ids.shape[1:] != (len(self.sizes), self.width):
+            raise ValueError(f"sparse_ids {ids.shape} and sparse_mask {mask.shape}: "
+                             f"not padded (B, {len(self.sizes)}, {self.width})")
+        wrong = (mask.reshape(rows, -1) != self.pattern).any(axis=0)
+        if wrong.any():
+            t = int(np.flatnonzero(wrong)[0]) // self.width
+            raise ValueError(f"table {t} does not hold exactly {self.sizes[t]} ids "
+                             "in every row")
+        out = {k: v for k, v in batch.items() if k != "sparse_mask"}
+        out["sparse_ids"] = np.take(ids.reshape(rows, -1), self.columns, axis=1)
+        return out
+
+
 class Trainer:
     def __init__(
         self,
@@ -113,6 +146,11 @@ class Trainer:
         self._train_step = (
             self._build_sparse_step() if self._sparse else self._build_step()
         )
+        bags = getattr(model_cfg, "bag_sizes", ())
+        if bags and self._sparse:
+            raise ValueError("the tiered-embedding step pools padded bags; "
+                             "a config with bag_sizes trains on the dense step")
+        self._pack = BagPacker(model_cfg) if bags else None
         self.history: list[StepMetrics] = []
         self.metrics = TrainMetrics()
         self.registry = registry or MetricsRegistry()
@@ -256,6 +294,9 @@ class Trainer:
                 te = t1
                 with tr.span("train.step", **labels):
                     with tr.span("train.put", **labels):
+                        if self._pack is not None:
+                            with tr.span("train.pack", **labels):
+                                batch = self._pack(batch)
                         jb = {k: jnp.asarray(v) for k, v in batch.items()}
                     with tr.span("train.dispatch", **labels):
                         params, opt, loss, gnorm = self._train_step(params, opt, jb)

@@ -138,3 +138,31 @@ def test_dlrm_train_step_compiles_at_full_width(one_chip):
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes > 3 * cfg.num_tables * ROWS * 128 * 4
     assert used < V5E_HBM_BYTES, used
+
+
+def test_dlrm_dcnv2_train_step_compiles_at_full_width(one_chip):
+    """MLPerf's DLRM-DCNv2 at its published widths, 200,000 rows per table
+    (the benchmark's cut), batch 2,048 a chip: the Trainer's dense step
+    takes the packed (B, 214) ids, gathers (B, 214, E) and never the
+    padded (B, T, 100, E), and the compiler takes it for one chip's HBM
+    (it refuses a step that does not fit).  Its temporary bytes are those
+    of the one-hot dlrm-criteo-1tb step, whose peak on the chip is 10.7 GB:
+    the dense table gradient, and no more for the multi-hot bags."""
+    from repro import configs as cfglib
+    from repro.optim import OptimizerConfig, adamw_init
+    from repro.train import Trainer
+
+    cfg = dataclasses.replace(cfglib.get_config("dlrm-dcnv2"), vocab_per_table=200_000)
+    opt_cfg = OptimizerConfig()
+    trainer = Trainer(cfg, opt_cfg)
+    params = jax.eval_shape(trainer.model.init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(lambda p: adamw_init(p, opt_cfg), params)
+    batch = trainer.model.input_specs(2048)
+    assert batch["sparse_ids"].shape == (2048, 214) and "sparse_mask" not in batch
+    place = lambda s: _spec(one_chip, s.shape, s.dtype)   # noqa: E731
+    args = jax.tree.map(place, (params, opt, batch))
+    compiled = trainer._train_step.lower(*args).compile()
+    text = compiled.as_text()
+    assert "[2048,214,128]" in text and "[2048,26,100,128]" not in text
+    table_bytes = cfg.num_tables * cfg.vocab_per_table * cfg.embed_dim * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.01 * table_bytes
